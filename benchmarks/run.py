@@ -15,6 +15,7 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from benchmarks import paper_tables as pt  # noqa: E402
+from repro.launch.compat import use_compile_cache  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ARTIFACT = os.path.join(ROOT, "BENCH_run.json")
@@ -100,6 +101,7 @@ def _engine_summary() -> list[tuple]:
 
 
 def main() -> None:
+    use_compile_cache(ROOT)
     rows: list[tuple] = []
     rows += pt.section_v_worked_example()
     rows += pt.tables_i_ii_nvme_models()

@@ -77,6 +77,7 @@ __all__ = [
     "fluid_two_tier",
     "fluid_two_tier_batched",
     "fluid_compile_count",
+    "fluid_solve_device",
     "reset_fluid_compile_count",
     "residence_times",
     "expected_response",
@@ -1176,6 +1177,7 @@ def fluid_two_tier(
 # on this).
 _FLUID_CACHE: dict = {}
 _FLUID_COMPILES = [0]
+_FLUID_DEVICE = [None]
 
 
 def fluid_compile_count() -> int:
@@ -1185,6 +1187,11 @@ def fluid_compile_count() -> int:
 
 def reset_fluid_compile_count() -> None:
     _FLUID_COMPILES[0] = 0
+
+
+def fluid_solve_device():
+    """The device the last batched fluid solve ran on (None before one)."""
+    return _FLUID_DEVICE[0]
 
 
 def _fluid_kernel(cfg):
@@ -1421,9 +1428,10 @@ def fluid_two_tier_batched(
     mlc = (np.asarray([ml[0][0], ml[0][1], ml[1][0], ml[1][1]], float)
            if ml is not None else None)
 
-    from jax.experimental import enable_x64
-    with enable_x64():
+    import jax
+    with jax.enable_x64(True):
         l1_e, l2_e, ys = fn(xs, fi.h, fi.l1, fi.l2, timeout, delays, mlc)
+        _FLUID_DEVICE[0] = next(iter(l1_e.devices()))
         ys = {key: np.moveaxis(np.asarray(val), 0, -1)
               for key, val in ys.items()}
         l1_e = np.asarray(l1_e)

@@ -3,40 +3,45 @@
 The reference engine (``repro.storage.tiered_store``) carries the full
 ``StoreState`` pytree through a ``lax.scan``, so every request round-trips
 cache tags, recency metadata, prediction rings and expert weights through
-HBM — the queue-starved access pattern that leaves the sweep's
-``engine_dispatch`` stage dominant (~65% of wall time on the gated
-288-point × 32-window grid, see ``BENCH_report.json``). This module fuses
-the whole request loop — lookup → policy decision → eviction → windowed
-scatter-add — per ``(shard, point)``:
+HBM. This module fuses the whole request loop — lookup → policy decision →
+eviction → windowed scatter-add — per ``(shard, point)`` stream row:
 
-- **Pallas kernel** (:func:`cache_scan_kernel`): one grid cell per stream
-  row keeps the cache tag/metadata arrays, LRU/LFU recency state,
-  prediction rings and online-learning expert weights in VMEM scratch
-  (SMEM for the scalar learner/prefetcher state) and loops over the
-  requests with elementwise one-hot updates — no per-step HBM round trip,
-  no scatter/gather.
-- **Pure-jax fallback** (:func:`repro.kernels.ref.cache_scan_ref`): the
-  same one-hot step as a ``lax.scan`` — the CPU production path and the
-  golden oracle, bit-identical to the kernel in interpret mode and to the
-  reference engine everywhere (integer one-hot updates are exact; the
-  float weight arithmetic calls the same ``online_learning`` routines).
+- **Pallas kernel** (:func:`cache_scan_kernel`): one grid row per stream
+  row keeps the cache tag/metadata arrays, prediction rings and expert
+  weights in VMEM scratch, the scalar learner/prefetcher state and every
+  counter in SMEM, and loops over the requests with elementwise one-hot
+  updates. Requests arrive in SMEM chunks along a second grid axis, next to
+  the matching rows of the Random-expert noise table in VMEM.
+- **XLA engine** (:func:`repro.kernels.ref.cache_scan_ref`): the same
+  one-hot step as a ``lax.scan``, vectorised over the rows of a megabatch —
+  the default engine on every platform, and the golden oracle for the
+  kernel.
 - **Hoisted PRNG** (:func:`repro.kernels.ref.cache_scan_noise`): the
   Random expert's per-step uniforms become a precomputed ``[len,
-  n_lines]`` table — bit-identical draws (same threefry chain), computed
-  once per compile and *shared* across every megabatch row (the table is
-  a vmap constant), instead of a sequential split+draw per request.
+  n_lines]`` table — bit-identical draws (same threefry chain), shared by
+  every row of a megabatch.
 
-Dispatch follows the ``REPRO_KERNELS`` convention of
-:mod:`repro.kernels.reuse_distance`: pure-jax fallback on this CPU
-container, compiled Pallas on a TPU backend, interpret-mode Pallas
-testable everywhere. :func:`cache_scan_compile_count` counts traces of
-the production engine (once per XLA compile under jit) exactly like
-``engine_compile_count`` / ``stream_compile_count``.
+**Engine-path rule.** :func:`fused_cache_scan` (one-shot, cold-start rows)
+runs the Pallas kernel iff (1) the caller asked for it (``pallas=True``,
+``engine="pallas"`` at the public entry points), (2) the computation is
+lowered for a TPU (:func:`repro.kernels.backend.kernel_or_xla`), (3) the
+row's noise table fits :data:`NOISE_TABLE_MAX` elements (the table both
+engines hoist), and (4) the kernel's VMEM working set at the row's
+``n_lines`` fits :data:`VMEM_BUDGET` (:func:`kernel_fits`). Otherwise the
+XLA engine runs. The kernel is not the default because it is the slower
+engine on a TPU v5e: one 288-point sweep megabatch (1,152 rows of 8,192
+requests, 256 lines) takes 14.5 s on the kernel and 1.15 s on the XLA
+engine — the kernel walks the rows one grid cell after another, while XLA
+steps all of them at once. The resumable chunk mode
+(``tiered_store.stream_chunk_engine``) always runs the XLA engine. The
+outputs carry the path id that ran, which the callers count
+(:func:`repro.kernels.backend.engine_path_counts`).
+:func:`cache_scan_compile_count` counts traces of the one-shot engine
+(once per XLA compile under jit).
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -44,42 +49,68 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.online_learning import N_EXPERTS
+from repro.kernels.backend import XLA, kernel_or_xla
 from repro.kernels.ref import cache_scan_noise, cache_scan_ref
 
 __all__ = [
+    "KERNEL_WEIGHT_ULP",
+    "NOISE_TABLE_MAX",
+    "VMEM_BUDGET",
     "cache_scan_kernel",
     "fused_cache_scan",
+    "kernel_fits",
     "cache_scan_compile_count",
     "reset_cache_scan_compile_count",
 ]
 
-# Mirrors kernels/ops.py: interpret-mode (pure-jax fallback) unless the
-# container bakes a real TPU toolchain.
-INTERPRET = os.environ.get("REPRO_KERNELS", "interpret") != "tpu"
-
-# Noise-table budget, elements. One-shot streams whose [len, n_lines]
-# Random-expert table would exceed this (f32 >16 MB) fall back to in-loop
-# PRNG splits — correctness is unaffected (same draws), only the hoisting
-# optimization is skipped. The Pallas kernel additionally requires the
-# table to fit its VMEM block (NOISE_VMEM_MAX elements).
+# Noise-table cap, elements. One-shot rows whose [len, n_lines] f32
+# Random-expert table would exceed it draw in the loop instead (same draws),
+# which only the XLA engine can do. The table is one array per compiled
+# program (a constant under the megabatch's vmaps), so HBM does not bind
+# here: 2^22 elements are 16 MiB of a v5e's 16 GiB. The cap is kept at
+# 2^22 because every engine that hoists the table materialises it whole
+# (on a CPU host, in host memory) and because it only decides how long a
+# row the kernel may take, which matters once the kernel is worth taking
+# (ROADMAP S4).
 NOISE_TABLE_MAX = 1 << 22
-NOISE_VMEM_MAX = 1 << 20
 
-# Trace-time compile counter for the fused engine (both the Pallas wrapper
-# and the ref fallback): increments once per trace, i.e. once per XLA
-# compile when called under jit — benchmarks/bench_engine.py gates on it.
+# VMEM the kernel may plan for, bytes: the noise-table chunk (double
+# buffered) plus the cache-state scratch. Mosaic's default scoped VMEM limit
+# on a v5e is 16 MiB; the compile rehearsal in tests/test_tpu_compile.py
+# compiles the kernel at the largest n_lines this budget admits.
+VMEM_BUDGET = 14 << 20
+# Noise rows streamed per grid step (upper bound; fewer when n_lines is
+# wide, see _chunk_rows).
+MAX_CHUNK = 2048
+
+# Largest f32 expert-weight difference between the compiled kernel and the
+# XLA engines measured on a TPU v5e, in ulp. The counters are bit-exact;
+# the weights read 0 ulp on the compiled goldens and on a 288-point sweep,
+# and 1 ulp on 4 of 24 weights of a 4-point sweep. Each op of the weight
+# update (pow, mean, sum, divide) reads 0 ulp alone, so the op that rounds
+# differently inside the compiled programs is not identified.
+KERNEL_WEIGHT_ULP = 1
+
+# Trace-time compile counter for the one-shot engine: increments once per
+# trace, i.e. once per XLA compile under jit.
 _CACHE_SCAN_COMPILES = [0]
 
 # SMEM scalar slots of the kernel (learner + stream-identifier state).
-_SM_EPOCH_MISSES, _SM_CHOSEN, _SM_LAST_MISS, _SM_STRIDE = 0, 1, 2, 3
-_SM_CONF, _SM_ISSUED, _SM_USEFUL = 4, 5, 6
+_SM_EPOCH_MISSES, _SM_NVALID, _SM_LAST_MISS = 0, 1, 2
+_SM_STRIDE, _SM_CONF, _SM_ISSUED = 3, 4, 5
 _N_SM = 8
+# Whole-row totals in the SMEM counter slab, in this order.
+_TOTALS = ("hits", "misses", "prefetch_hits", "tier2_reads", "tier2_writes",
+           "evictions")
+# Windowed counters, one W-wide row each, after the totals and expert_use.
+_WIN = ("win_requests", "win_hits", "win_misses", "win_prefetch_hits",
+        "win_tier2_reads", "win_tier2_writes", "win_evictions")
 
 _BIG = jnp.iinfo(jnp.int32).max
 
 
 def cache_scan_compile_count() -> int:
-    """Number of traces (== XLA compiles under jit) of the fused engine."""
+    """Number of traces (== XLA compiles under jit) of the one-shot engine."""
     return _CACHE_SCAN_COMPILES[0]
 
 
@@ -87,167 +118,190 @@ def reset_cache_scan_compile_count() -> None:
     _CACHE_SCAN_COMPILES[0] = 0
 
 
+def _pad(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _state_bytes(n_lines: int) -> int:
+    # Four [1, n_lines] int32 scratch arrays, each tiled to 8 sublanes.
+    return 4 * 8 * _pad(n_lines, 128) * 4
+
+
+def _chunk_rows(length: int, n_lines: int) -> int:
+    """Noise rows per grid step: the largest power of two (at most
+    MAX_CHUNK, at least 128) whose double-buffered block fits the budget
+    left after the state scratch. The whole row when it is shorter."""
+    room = VMEM_BUDGET - _state_bytes(n_lines)
+    rows = MAX_CHUNK
+    while rows > 128 and 2 * rows * _pad(n_lines, 128) * 4 > room:
+        rows //= 2
+    return length if length <= rows else rows
+
+
+def kernel_fits(length: int, n_lines: int) -> bool:
+    """Conditions (3) and (4) of the engine-path rule (module docstring)."""
+    if length * n_lines > NOISE_TABLE_MAX:
+        return False
+    rows = _pad(_chunk_rows(length, n_lines), 8)
+    return (_state_bytes(n_lines) + 2 * rows * _pad(n_lines, 128) * 4
+            <= VMEM_BUDGET)
+
+
 def fused_cache_scan(cfg, hyper, state0, acc0, pages, writes, win, *,
-                     n_windows: int, unroll: int = 1, masked: bool = False,
-                     interpret=None):
-    """Production fused engine for one stream row: ``(state0, acc0, pages
-    [L], writes [L], win [L]) -> (final_state, acc)``.
+                     n_windows: int, unroll: int = 1, pallas: bool = False):
+    """One-shot fused engine for one cold-start stream row: ``(state0,
+    acc0, pages [L], writes [L], win [L]) -> (final_weights, acc, path)``.
 
     Plain traceable function (inlines into the caller's jit; the compile
     counter increments once per outer XLA compile). ``cfg`` supplies the
     structural knobs (``epoch_width``, ``pred_cap``, ``prefetch``,
-    ``prefetch_width``), ``hyper`` the traced scalar knobs. ``masked=True``
-    is the resumable chunk-engine mode: pads (``win >= n_windows``) leave
-    the carried state untouched, and the PRNG stays in-loop (the carried
-    key must advance per real request; a per-shard noise table would also
-    defeat the chunk path's bounded-memory contract). The one-shot mode
-    hoists the Random expert's draws into a shared noise table instead
-    (see :func:`repro.kernels.ref.cache_scan_noise`).
-
-    On a TPU backend (``REPRO_KERNELS=tpu``) the one-shot mode routes to
-    :func:`cache_scan_kernel` (a fresh cold-start row, exactly what the
-    one-shot callers construct); everything else runs the pure-jax
-    fallback — bit-identical either way.
-    """
+    ``prefetch_width``), ``hyper`` the traced scalar knobs; ``state0`` and
+    ``acc0`` are the cold :func:`~repro.storage.tiered_store.init_store`
+    state and zeroed accumulators every one-shot caller passes.
+    ``pallas=True`` asks for the Pallas kernel. The engine follows the rule
+    in the module docstring; ``path`` is the int32
+    :data:`~repro.kernels.backend.PALLAS` / ``XLA`` id of the engine that
+    ran."""
     _CACHE_SCAN_COMPILES[0] += 1  # trace-time: once per XLA compile
-    if interpret is None:
-        interpret = INTERPRET
     n_lines = state0.cache.tags.shape[-1]
     length = pages.shape[0]
-    use_table = (not masked) and length * n_lines <= NOISE_TABLE_MAX
-    noise = cache_scan_noise(state0.key, length, n_lines) if use_table \
-        else None
-    if interpret or not use_table \
-            or length * n_lines > NOISE_VMEM_MAX:
-        return cache_scan_ref(
-            state0, acc0, pages, writes, win, hyper, noise,
+    noise = (cache_scan_noise(state0.key, length, n_lines)
+             if length * n_lines <= NOISE_TABLE_MAX else None)
+
+    def xla(p, w, wi):
+        final, acc = cache_scan_ref(
+            state0, acc0, p, w, wi, hyper, noise,
             epoch_width=cfg.epoch_width, pred_cap=cfg.pred_cap,
             prefetch=cfg.prefetch, prefetch_width=cfg.prefetch_width,
-            n_windows=n_windows, unroll=unroll, masked=masked,
+            n_windows=n_windows, unroll=unroll,
         )
-    out = cache_scan_kernel(
-        pages[None], writes[None], win[None], noise,
-        hyper.alpha, hyper.beta, hyper.threshold, hyper.policy_idx,
-        n_lines=n_lines, epoch_width=cfg.epoch_width,
-        pred_cap=cfg.pred_cap, prefetch=cfg.prefetch,
-        prefetch_width=cfg.prefetch_width,
-        prefetch_buf=state0.pf.ptags.shape[-1], n_windows=n_windows,
-        interpret=False,
-    )
-    # The kernel runs the row from the cold init state (what every one-shot
-    # caller passes) and returns the accumulators directly; only the final
-    # expert weights of the state are observable downstream.
-    acc = jax.tree.map(
-        lambda a0, a: a[0].reshape(jnp.shape(a0)).astype(a0.dtype),
-        acc0, type(acc0)(**{f: out[f] for f in acc0._fields}))
-    state = state0._replace(
-        ols=state0.ols._replace(weights=out["final_weights"][0]),
-        t=state0.t + length)
-    return state, acc
+        return final.ols.weights, acc
+
+    def kernel(p, w, wi):
+        out = cache_scan_kernel(
+            p[None], w[None], wi[None], noise,
+            hyper.alpha, hyper.beta, hyper.threshold, hyper.policy_idx,
+            n_lines=n_lines, epoch_width=cfg.epoch_width,
+            pred_cap=cfg.pred_cap, prefetch=cfg.prefetch,
+            prefetch_width=cfg.prefetch_width,
+            prefetch_buf=state0.pf.ptags.shape[-1], n_windows=n_windows,
+        )
+        acc = type(acc0)(**{
+            f: out[f][0].reshape(jnp.shape(a0)).astype(a0.dtype)
+            for f, a0 in zip(acc0._fields, acc0)})
+        return out["final_weights"][0], acc
+
+    if not pallas or noise is None or not kernel_fits(length, n_lines):
+        weights, acc = xla(pages, writes, win)
+        return weights, acc, jnp.int32(XLA)
+    (weights, acc), path = kernel_or_xla(kernel, xla, pages, writes, win)
+    return weights, acc, path
 
 
-def _cache_scan_body(pages_ref, writes_ref, win_ref, noise_ref,
-                     alpha_ref, beta_ref, thr_ref, pol_ref,
-                     scal_ref, eu_ref, winc_ref, weu_ref, ww_ref, fw_ref,
-                     tags_s, valid_s, dirty_s, freq_s, ts_s,
-                     pred_s, wts_s, predn_s, mispred_s, ptags_s, pvalid_s,
-                     sm, *, length, n_lines, epoch_width, pred_cap,
+def _cache_scan_body(knob_ref, pol_ref, pages_ref, flags_ref, noise_ref,
+                     cnt_ref, ww_ref, fw_ref,
+                     tags_s, dirty_s, freq_s, ts_s, pred_s, wts_s, predn_s,
+                     mispred_s, ptags_s, pvalid_s, sm, cnt_s, ww_s, *,
+                     length, chunk, n_chunks, n_lines, epoch_width, pred_cap,
                      prefetch, prefetch_width, prefetch_buf, n_windows):
-    """One grid cell = one stream row, state resident in VMEM/SMEM scratch.
+    """One grid step = one chunk of one stream row; the row's state stays
+    resident in VMEM/SMEM scratch across its chunks.
 
-    Mirrors :func:`repro.kernels.ref.fused_cache_step` op for op (interpret
-    mode is bit-identical by construction); arg-reductions are spelled as
-    first-index min-selects (``min(where(mask, iota, BIG))``), which equal
-    ``argmin``/``argmax`` first-match semantics exactly. The prediction
-    rings are stored transposed (``[pred_cap, E]``) so the ring-cursor
-    write is a row-iota compare against the ``[1, E]`` cursor — lane
-    layouts only, no in-kernel transposes.
+    Mirrors :func:`repro.kernels.ref.fused_cache_step` op for op: lines fill
+    strictly in order (a scalar fill count replaces the ``valid`` array),
+    the victim arg-reductions are unmasked first-index min-selects
+    (``min(where(mask, iota, BIG))`` equals ``argmin``/``argmax``), and the
+    prediction rings are stored transposed (``[C, E]``) with the cursor kept
+    modulo ``C``. Per-request operands and every counter are SMEM scalars;
+    the weight update is gated on real requests exactly as the XLA engine.
+    Counters and window weights accumulate in scratch and reach the output
+    blocks once, at the row's last chunk, so the result never depends on an
+    output block staying resident across grid steps.
     """
     i32, f32 = jnp.int32, jnp.float32
-    E = N_EXPERTS
+    E, W, C = N_EXPERTS, n_windows, pred_cap
+    c = pl.program_id(1)
     line = jax.lax.broadcasted_iota(i32, (1, n_lines), 1)
     eline = jax.lax.broadcasted_iota(i32, (1, E), 1)
+    wrow = jax.lax.broadcasted_iota(i32, (W, E), 0)
+    base_eu = len(_TOTALS)
+    base_w = base_eu + E
+    base_weu = base_w + len(_WIN) * W
 
-    # Cold start: init_store() state, zeroed accumulators.
-    tags_s[...] = jnp.full((1, n_lines), -1, i32)
-    valid_s[...] = jnp.zeros((1, n_lines), i32)
-    dirty_s[...] = jnp.zeros((1, n_lines), i32)
-    freq_s[...] = jnp.zeros((1, n_lines), i32)
-    ts_s[...] = jnp.zeros((1, n_lines), i32)
-    pred_s[...] = jnp.full((pred_cap, E), -1, i32)
-    wts_s[...] = jnp.full((1, E), 1.0 / E, f32)
-    predn_s[...] = jnp.zeros((1, E), i32)
-    mispred_s[...] = jnp.zeros((1, E), i32)
-    ptags_s[...] = jnp.full((1, prefetch_buf), -1, i32)
-    pvalid_s[...] = jnp.zeros((1, prefetch_buf), i32)
-    for j in range(_N_SM):
-        sm[j] = jnp.asarray(-1 if j == _SM_LAST_MISS else 0, i32)
-    scal_ref[...] = jnp.zeros_like(scal_ref)
-    eu_ref[...] = jnp.zeros_like(eu_ref)
-    winc_ref[...] = jnp.zeros_like(winc_ref)
-    weu_ref[...] = jnp.zeros_like(weu_ref)
-    ww_ref[...] = jnp.zeros_like(ww_ref)
+    @pl.when(c == 0)
+    def _cold_start():
+        tags_s[...] = jnp.full((1, n_lines), -1, i32)
+        dirty_s[...] = jnp.zeros((1, n_lines), i32)
+        freq_s[...] = jnp.zeros((1, n_lines), i32)
+        ts_s[...] = jnp.zeros((1, n_lines), i32)
+        pred_s[...] = jnp.full((C, E), -1, i32)
+        wts_s[...] = jnp.full((1, E), 1.0 / E, f32)
+        predn_s[...] = jnp.zeros((1, E), i32)
+        mispred_s[...] = jnp.zeros((1, E), i32)
+        ptags_s[...] = jnp.full((1, prefetch_buf), -1, i32)
+        pvalid_s[...] = jnp.zeros((1, prefetch_buf), i32)
+        for k in range(_N_SM):
+            sm[k] = jnp.int32(-1 if k == _SM_LAST_MISS else 0)
 
-    alpha = alpha_ref[0, 0]
-    beta = beta_ref[0, 0]
-    thr = thr_ref[0, 0]
+        def zero(k, carry):
+            cnt_s[k] = jnp.int32(0)
+            return carry
+
+        jax.lax.fori_loop(0, cnt_s.shape[0], zero, 0)
+        ww_s[...] = jnp.zeros((W, E), f32)
+
+    alpha, beta, thr = knob_ref[0, 0], knob_ref[0, 1], knob_ref[0, 2]
     pol = pol_ref[0, 0]
 
     def first_idx(mask, iota):
         return jnp.min(jnp.where(mask, iota, _BIG))
 
-    def step(t, carry):
-        page = pages_ref[0, t]
-        is_w = writes_ref[0, t] != 0
-        win_i = win_ref[0, t]
-        nrow = noise_ref[pl.ds(t, 1), :]                  # (1, n_lines)
-        tags, freq, ts = tags_s[...], freq_s[...], ts_s[...]
-        valid, dirty = valid_s[...] != 0, dirty_s[...] != 0
+    def any_(mask):
+        return jnp.max(mask.astype(i32)) > 0
 
-        # --- lookup ---
-        match = valid & (tags == page)
-        hit = jnp.any(match)
-        hit_oh = line == first_idx(match, line)
-        ts_hit = jnp.where(hit_oh, t, ts)
-        freq_hit = freq + hit_oh.astype(i32)
-        dirty_hit = dirty | (hit_oh & is_w)
+    def add(k, v):
+        cnt_s[k] = cnt_s[k] + v.astype(i32)
+
+    def step(j, carry):
+        t = c * chunk + j
+        page = pages_ref[0, j]
+        fl = flags_ref[0, j]
+        is_w = (fl & 1) == 1
+        win_i = fl >> 1
+        nrow = noise_ref[pl.ds(j, 1), :]                  # (1, n_lines)
+        tags, dirty = tags_s[...], dirty_s[...]
+        freq, ts = freq_s[...], ts_s[...]
+
+        # --- lookup: free lines hold -1, never a page id ---
+        match = tags == page
+        hit = any_(match)
+        miss = jnp.logical_not(hit)
 
         # --- miss bookkeeping ---
-        miss = ~hit
         hit_pred = jnp.max((pred_s[...] == page).astype(i32), axis=0,
                            keepdims=True)                 # (1, E)
-        mispred_s[...] += jnp.where(miss, hit_pred, 0)
-        sm[_SM_EPOCH_MISSES] = (sm[_SM_EPOCH_MISSES]
-                                + jnp.where(miss, 1, 0).astype(i32))
+        mis = mispred_s[...] + jnp.where(miss, hit_pred, 0)
+        em = sm[_SM_EPOCH_MISSES] + miss.astype(i32)
         if prefetch:
-            ptags, pvalid = ptags_s[...], pvalid_s[...] != 0
-            pmatch = pvalid & (ptags == page)
-            in_buf = jnp.any(pmatch)
-            pvalid = jnp.where(miss & pmatch, False, pvalid)
-            pvalid_s[...] = pvalid.astype(i32)
-            sm[_SM_USEFUL] = (sm[_SM_USEFUL]
-                              + jnp.where(miss & in_buf, 1, 0).astype(i32))
-            promoted = miss & in_buf
+            ptags, pvalid = ptags_s[...], pvalid_s[...]
+            pmatch = (pvalid != 0) & (ptags == page)
+            promoted = miss & any_(pmatch)
+            pvalid = jnp.where(miss, jnp.where(pmatch, 0, pvalid), pvalid)
         else:
             promoted = jnp.zeros((), bool)
 
-        free = ~valid
-        has_free = jnp.any(free)
-        free_idx = first_idx(free, line)
+        n_valid = sm[_SM_NVALID]
+        has_free = n_valid < n_lines
 
-        # --- GetVictim ---
-        ts_m = jnp.where(valid, ts, _BIG)
-        fq_m = jnp.where(valid, freq, _BIG)
-        lru = first_idx(ts_m == jnp.min(ts_m), line)
-        lfu = first_idx(fq_m == jnp.min(fq_m), line)
-        nz = jnp.where(valid, nrow, -1.0)
-        rnd = first_idx(nz == jnp.max(nz), line)
+        # --- GetVictim (unmasked: only observable when the cache is full) ---
+        lru = first_idx(ts == jnp.min(ts), line)
+        lfu = first_idx(freq == jnp.min(freq), line)
+        rnd = first_idx(nrow == jnp.max(nrow), line)
         w = wts_s[...]
         s = jnp.sum(w)
         probs = jnp.where(s > 0, w / s, 1.0 / E)
         learned = first_idx(probs == jnp.max(probs), eline)
         chosen = jnp.where(pol >= 0, jnp.clip(pol, 0, E - 1), learned)
-        # E == 3 select chains (the expert contract of online_learning).
         victim_idx = jnp.where(chosen == 0, lru,
                                jnp.where(chosen == 1, lfu, rnd))
         vp_lru = jnp.sum(jnp.where(line == lru, tags, 0))
@@ -256,31 +310,28 @@ def _cache_scan_body(pages_ref, writes_ref, win_ref, noise_ref,
         victim_pages = jnp.where(eline == 0, vp_lru,
                                  jnp.where(eline == 1, vp_lfu, vp_rnd))
 
-        evict = miss & ~has_free
-        slot = jnp.where(has_free, free_idx, victim_idx)
+        evict = miss & jnp.logical_not(has_free)
+        slot = jnp.where(has_free, n_valid, victim_idx)
         slot_oh = line == slot
-        writeback = evict & jnp.any(slot_oh & dirty)
+        writeback = evict & any_(slot_oh & (dirty != 0))
 
-        # --- prediction rings (transposed [C, E] layout) ---
-        ring = predn_s[...] % pred_cap                    # (1, E)
-        riota = jax.lax.broadcasted_iota(i32, (pred_cap, E), 0)
-        pred_new = jnp.where(riota == ring, victim_pages, pred_s[...])
-        pred_s[...] = jnp.where(evict, pred_new, pred_s[...])
-        predn_s[...] = jnp.where(evict, predn_s[...] + 1, predn_s[...])
-        sm[_SM_CHOSEN] = jnp.where(evict, chosen, sm[_SM_CHOSEN])
+        # --- prediction rings (transposed [C, E], cursor modulo C) ---
+        predn = predn_s[...]
+        riota = jax.lax.broadcasted_iota(i32, (C, E), 0)
+        pred = pred_s[...]
+        pred = jnp.where(evict & (riota == predn), victim_pages, pred)
+        predn = jnp.where(evict, jnp.where(predn + 1 == C, 0, predn + 1),
+                          predn)
 
-        # --- insert + merge ---
-        tags_n = jnp.where(miss, jnp.where(slot_oh, page, tags), tags)
-        valid_n = jnp.where(miss, valid | slot_oh, valid)
-        tags_s[...] = tags_n
-        valid_s[...] = valid_n.astype(i32)
+        # --- insert / touch (one select per array) ---
+        touch = jnp.where(miss, slot_oh.astype(i32), match.astype(i32)) != 0
+        tags = jnp.where(touch, page, tags)
+        tags_s[...] = tags
         dirty_s[...] = jnp.where(
-            miss, jnp.where(slot_oh, is_w, dirty),
-            jnp.where(hit, dirty_hit, dirty)).astype(i32)
-        freq_s[...] = jnp.where(miss, jnp.where(slot_oh, 1, freq),
-                                jnp.where(hit, freq_hit, freq))
-        ts_s[...] = jnp.where(miss, jnp.where(slot_oh, t, ts),
-                              jnp.where(hit, ts_hit, ts))
+            touch, jnp.where(hit, dirty, 0) | is_w.astype(i32), dirty)
+        freq_s[...] = jnp.where(touch, jnp.where(miss, 0, freq) + 1, freq)
+        ts_s[...] = jnp.where(touch, t, ts)
+        sm[_SM_NVALID] = n_valid + (miss & has_free).astype(i32)
 
         # --- stream identifier + prefetch issue ---
         if prefetch:
@@ -288,95 +339,77 @@ def _cache_scan_body(pages_ref, writes_ref, win_ref, noise_ref,
             conf = sm[_SM_CONF]
             delta = page - last_miss
             same = (delta == stride) & (last_miss >= 0) & (delta != 0)
-            conf_o = jnp.where(same, conf + 1,
-                               jnp.where(delta != 0, 1, conf))
-            stride_o = jnp.where(same, stride,
-                                 jnp.where(delta != 0, delta, stride))
-            stride_n = jnp.where(miss, stride_o, stride)
-            conf_n = jnp.where(miss, conf_o, conf)
+            conf_n = jnp.where(miss, jnp.where(
+                same, conf + 1, jnp.where(delta != 0, 1, conf)), conf)
+            stride_n = jnp.where(miss & ~same & (delta != 0), delta, stride)
             sm[_SM_LAST_MISS] = jnp.where(miss, page, last_miss)
             sm[_SM_STRIDE] = stride_n
             sm[_SM_CONF] = conf_n
             n_before = sm[_SM_ISSUED]
             active = conf_n >= 2
             bline = jax.lax.broadcasted_iota(i32, (1, prefetch_buf), 1)
-
-            def pbody(k, c):
-                ptg, pvl, issued = c
+            ptg, pvl, issued = ptags, pvalid, n_before
+            for k in range(prefetch_width):
                 cand = page + (k + 1) * stride_n
-                in_cache = jnp.any(valid_n & (tags_n == cand))
-                in_buf2 = jnp.any(pvl & (ptg == cand))
-                bfree = ~pvl
-                do = (active & jnp.any(bfree) & ~in_cache & ~in_buf2
+                in_cache = any_(tags == cand)
+                in_buf2 = any_((pvl != 0) & (ptg == cand))
+                bfree = pvl == 0
+                do = (active & any_(bfree) & ~in_cache & ~in_buf2
                       & (cand >= 0))
                 boh = (bline == first_idx(bfree, bline)) & do
-                return (jnp.where(boh, cand, ptg), pvl | boh,
-                        issued + jnp.where(do, 1, 0).astype(i32))
-
-            pt0, pv0 = ptags_s[...], pvalid_s[...] != 0
-            pt1, pv1, iss1 = jax.lax.fori_loop(
-                0, prefetch_width, pbody, (pt0, pv0, n_before))
-            ptags_s[...] = jnp.where(miss, pt1, pt0)
-            pvalid_s[...] = jnp.where(miss, pv1, pv0).astype(i32)
-            issued_n = jnp.where(miss, iss1, n_before)
+                ptg = jnp.where(boh, cand, ptg)
+                pvl = jnp.where(boh, 1, pvl)
+                issued = issued + do.astype(i32)
+            ptags_s[...] = jnp.where(miss, ptg, ptags)
+            pvalid_s[...] = jnp.where(miss, pvl, pvalid)
+            issued_n = jnp.where(miss, issued, n_before)
             sm[_SM_ISSUED] = issued_n
-            prefetch_fetches = jnp.where(miss, issued_n - n_before, 0)
+            prefetch_fetches = issued_n - n_before
         else:
-            prefetch_fetches = jnp.zeros((), i32)
+            prefetch_fetches = jnp.int32(0)
 
-        # --- epoch boundary (WeightAdjust, ws policy only) ---
-        do_adj = ((t + 1) % epoch_width == 0) & (pol < 0)
-        em = sm[_SM_EPOCH_MISSES]
-        mis = mispred_s[...]
+        # --- epoch boundary (WeightAdjust, ws policy, real requests) ---
+        do_adj = (((t + 1) % epoch_width == 0) & (pol < 0)
+                  & (win_i < W))
         losses = jnp.where(mis.astype(f32) >= thr * em.astype(f32),
                            mis, 0).astype(f32)
-        prev = wts_s[...]
-        wadj = prev * jnp.power(beta, losses)
-        wadj = wadj + alpha * jnp.mean(prev - wadj)
+        wadj = w * jnp.power(beta, losses)
+        wadj = wadj + alpha * jnp.mean(w - wadj)
         wadj = jnp.maximum(wadj, 1e-8)
         wadj = wadj / jnp.sum(wadj)
-        wts_s[...] = jnp.where(do_adj, wadj, prev)
-        pred_s[...] = jnp.where(do_adj, -1, pred_s[...])
-        predn_s[...] = jnp.where(do_adj, 0, predn_s[...])
+        w = jnp.where(do_adj, wadj, w)
+        wts_s[...] = w
+        pred_s[...] = jnp.where(do_adj, -1, pred)
+        predn_s[...] = jnp.where(do_adj, 0, predn)
         mispred_s[...] = jnp.where(do_adj, 0, mis)
         sm[_SM_EPOCH_MISSES] = jnp.where(do_adj, 0, em)
 
-        # --- fold (one-hot accumulators; pad win_i matches no slot) ---
-        hit_c = hit.astype(i32)
-        miss_c = miss.astype(i32)
-        pfh_c = promoted.astype(i32)
-        t2r_c = (miss & ~promoted).astype(i32) + prefetch_fetches
-        t2w_c = writeback.astype(i32)
-        ev_c = evict.astype(i32)
+        # --- fold: totals count every position, windows drop pads ---
+        t2r = (miss & ~promoted).astype(i32) + prefetch_fetches
+        vals = (hit, miss, promoted, t2r, writeback, evict)
+        for k, v in enumerate(vals):
+            add(k, v)
         expert = jnp.where(evict, chosen, 0)
-        sc = jax.lax.broadcasted_iota(i32, (1, 8), 1)
-        scal_ref[...] += jnp.where(
-            sc == 0, hit_c, jnp.where(
-                sc == 1, miss_c, jnp.where(
-                    sc == 2, pfh_c, jnp.where(
-                        sc == 3, t2r_c, jnp.where(
-                            sc == 4, t2w_c, jnp.where(
-                                sc == 5, ev_c, 0))))))
-        eu_ref[...] += jnp.where(eline == expert, ev_c, 0)
-        r7 = jax.lax.broadcasted_iota(i32, (1, 7, n_windows), 1)
-        w7 = jax.lax.broadcasted_iota(i32, (1, 7, n_windows), 2)
-        vals = jnp.where(
-            r7 == 0, 1, jnp.where(
-                r7 == 1, hit_c, jnp.where(
-                    r7 == 2, miss_c, jnp.where(
-                        r7 == 3, pfh_c, jnp.where(
-                            r7 == 4, t2r_c, jnp.where(
-                                r7 == 5, t2w_c, ev_c))))))
-        winc_ref[...] += jnp.where(w7 == win_i, vals, 0)
-        wW = jax.lax.broadcasted_iota(i32, (1, n_windows, E), 1)
-        eE = jax.lax.broadcasted_iota(i32, (1, n_windows, E), 2)
-        weu_ref[...] += jnp.where((wW == win_i) & (eE == expert), ev_c, 0)
-        ww_ref[...] = jnp.where(wW == win_i, wts_s[...][:, None, :],
-                                ww_ref[...])
+        add(base_eu + expert, evict)
+        real = (win_i < W).astype(i32)
+        wi = jnp.minimum(win_i, W - 1)
+        for r, v in enumerate((jnp.int32(1),) + vals):
+            add(base_w + r * W + wi, v.astype(i32) * real)
+        add(base_weu + wi * E + expert, evict.astype(i32) * real)
+        ww_s[...] = jnp.where(wrow == win_i, w, ww_s[...])
         return carry
 
-    jax.lax.fori_loop(0, length, step, jnp.zeros((), i32))
-    fw_ref[...] = wts_s[...]
+    jax.lax.fori_loop(0, jnp.minimum(chunk, length - c * chunk), step, 0)
+
+    @pl.when(c == n_chunks - 1)
+    def _emit():
+        def copy(k, carry):
+            cnt_ref[0, k] = cnt_s[k]
+            return carry
+
+        jax.lax.fori_loop(0, cnt_s.shape[0], copy, 0)
+        ww_ref[...] = ww_s[...]
+        fw_ref[...] = wts_s[...]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -400,90 +433,107 @@ def cache_scan_kernel(
 ) -> dict:
     """Batched Pallas cache scan: each of the ``B`` rows runs the whole
     request loop from the cold :func:`~repro.storage.tiered_store.init_store`
-    state inside one grid cell, tier-1 state resident in VMEM scratch.
+    state, tier-1 state resident in VMEM scratch, over a ``(B, chunks)``
+    grid.
 
     Returns the accumulator dict (keys = the reference ``_Accum`` fields
     plus ``final_weights``): scalar counters ``[B]``, windowed counters
     ``[B, n_windows]``, ``win_expert_use``/``win_weights``
     ``[B, n_windows, E]``. Bit-identical to
     :func:`repro.kernels.ref.cache_scan_ref` over each row with the same
-    ``noise`` table (golden-tested in interpret mode)."""
+    ``noise`` table in interpret mode; compiled for a TPU, the counters are
+    bit-identical and the weights within :data:`KERNEL_WEIGHT_ULP`."""
     B, L = pages.shape
-    E = N_EXPERTS
-    W = n_windows
+    E, W = N_EXPERTS, n_windows
     i32, f32 = jnp.int32, jnp.float32
-    pages = jnp.asarray(pages, i32)
-    writes = jnp.asarray(writes).astype(i32)
-    win = jnp.asarray(win, i32)
-    noise = jnp.asarray(noise, f32)
     # The ring only ever holds min(pred_cap, epoch_width) live entries:
     # under ws it is cleared every epoch (<= epoch_width evictions between
     # resets), and under fixed policies it is unobservable (weights never
     # adjust) — same truncation as cache_scan_ref, bit-exact.
     pred_cap = min(pred_cap, epoch_width)
-    _CACHE_SCAN_COMPILES[0] += 1  # trace-time: once per XLA compile
+    chunk = _chunk_rows(L, n_lines)
+    n_chunks = -(-L // chunk)
+    lp = n_chunks * chunk
 
-    def knob(x, dtype):
-        x = jnp.asarray(x, dtype)
-        return jnp.broadcast_to(x.reshape(-1, 1), (B, 1))
+    def rows(x, dtype):
+        x = jnp.asarray(x).astype(dtype)
+        return jnp.pad(x, ((0, 0), (0, lp - L))) if lp != L else x
 
-    row = pl.BlockSpec((1, L), lambda b: (b, 0))
-    smem1 = pl.BlockSpec((1, 1), lambda b: (b, 0),
-                         memory_space=pltpu.SMEM)
-    out = pl.pallas_call(
+    pages = rows(pages, i32)
+    # Per-request flags: window id * 2 + is_write, one SMEM word.
+    flags = rows(jnp.asarray(win, i32) * 2 + jnp.asarray(writes).astype(i32),
+                 i32)
+    noise = jnp.asarray(noise, f32)
+    if lp != L:
+        noise = jnp.pad(noise, ((0, lp - L), (0, 0)))
+    # Rows carry a unit middle axis so every block's last two dimensions
+    # equal the array's (Mosaic's tiling rule for squeezed row blocks).
+    pages, flags = pages[:, None], flags[:, None]
+    knobs = jnp.stack([jnp.broadcast_to(jnp.asarray(x, f32), (B,))
+                       for x in (alpha, beta, threshold)], axis=1)[:, None]
+    pol = jnp.broadcast_to(jnp.asarray(policy_idx, i32), (B,)).reshape(
+        B, 1, 1)
+    n_cnt = len(_TOTALS) + E + len(_WIN) * W + W * E
+    vma = frozenset().union(*(jax.typeof(x).vma for x in
+                              (pages, flags, noise, knobs, pol)))
+
+    def smem_row(width):
+        return pl.BlockSpec((None, 1, width), lambda b, c: (b, 0, 0),
+                            memory_space=pltpu.SMEM)
+
+    stream = pl.BlockSpec((None, 1, chunk), lambda b, c: (b, 0, c),
+                          memory_space=pltpu.SMEM)
+    cnt, ww, fw = pl.pallas_call(
         functools.partial(
-            _cache_scan_body, length=L, n_lines=n_lines,
+            _cache_scan_body, length=L, chunk=chunk, n_chunks=n_chunks,
+            n_lines=n_lines,
             epoch_width=epoch_width, pred_cap=pred_cap, prefetch=prefetch,
             prefetch_width=prefetch_width, prefetch_buf=prefetch_buf,
             n_windows=W),
-        grid=(B,),
+        grid=(B, n_chunks),
         in_specs=[
-            row, row, row,
-            pl.BlockSpec((L, n_lines), lambda b: (0, 0)),  # shared noise
-            smem1, smem1, smem1, smem1,
+            smem_row(3), smem_row(1), stream, stream,
+            pl.BlockSpec((chunk, n_lines), lambda b, c: (c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 8), lambda b: (b, 0)),
-            pl.BlockSpec((1, E), lambda b: (b, 0)),
-            pl.BlockSpec((1, 7, W), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, W, E), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, W, E), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, E), lambda b: (b, 0)),
+            smem_row(n_cnt),
+            pl.BlockSpec((None, W, E), lambda b, c: (b, 0, 0)),
+            pl.BlockSpec((None, 1, E), lambda b, c: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, 8), i32),       # packed scalar totals
-            jax.ShapeDtypeStruct((B, E), i32),       # expert_use
-            jax.ShapeDtypeStruct((B, 7, W), i32),    # packed win counters
-            jax.ShapeDtypeStruct((B, W, E), i32),    # win_expert_use
-            jax.ShapeDtypeStruct((B, W, E), f32),    # win_weights
-            jax.ShapeDtypeStruct((B, E), f32),       # final_weights
+            jax.ShapeDtypeStruct((B, 1, n_cnt), i32, vma=vma),
+            jax.ShapeDtypeStruct((B, W, E), f32, vma=vma),
+            jax.ShapeDtypeStruct((B, 1, E), f32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, n_lines), i32),   # tags
-            pltpu.VMEM((1, n_lines), i32),   # valid
             pltpu.VMEM((1, n_lines), i32),   # dirty
             pltpu.VMEM((1, n_lines), i32),   # freq
             pltpu.VMEM((1, n_lines), i32),   # ts
             pltpu.VMEM((pred_cap, E), i32),  # prediction rings (transposed)
             pltpu.VMEM((1, E), f32),         # expert weights
-            pltpu.VMEM((1, E), i32),         # pred_n
+            pltpu.VMEM((1, E), i32),         # ring cursor
             pltpu.VMEM((1, E), i32),         # mispred
             pltpu.VMEM((1, prefetch_buf), i32),  # prefetch tags
             pltpu.VMEM((1, prefetch_buf), i32),  # prefetch valid
             pltpu.SMEM((_N_SM,), i32),       # scalar learner/prefetch state
+            pltpu.SMEM((n_cnt,), i32),       # counters
+            pltpu.VMEM((W, E), f32),         # window weights
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(pages, writes, win, noise,
-      knob(alpha, f32), knob(beta, f32), knob(threshold, f32),
-      knob(policy_idx, i32))
-    scal, eu, winc, weu, ww, fw = out
-    return dict(
-        hits=scal[:, 0], misses=scal[:, 1], prefetch_hits=scal[:, 2],
-        tier2_reads=scal[:, 3], tier2_writes=scal[:, 4],
-        evictions=scal[:, 5], expert_use=eu,
-        win_requests=winc[:, 0], win_hits=winc[:, 1],
-        win_misses=winc[:, 2], win_prefetch_hits=winc[:, 3],
-        win_tier2_reads=winc[:, 4], win_tier2_writes=winc[:, 5],
-        win_evictions=winc[:, 6], win_expert_use=weu, win_weights=ww,
-        final_weights=fw,
-    )
+        name="cache_scan",
+    )(knobs, pol, pages, flags, noise)
+    cnt = cnt[:, 0]
+    out = {f: cnt[:, k] for k, f in enumerate(_TOTALS)}
+    base = len(_TOTALS)
+    out["expert_use"] = cnt[:, base:base + E]
+    base += E
+    for r, f in enumerate(_WIN):
+        out[f] = cnt[:, base + r * W: base + (r + 1) * W]
+    base += len(_WIN) * W
+    out["win_expert_use"] = cnt[:, base:].reshape(B, W, E)
+    out["win_weights"] = ww
+    out["final_weights"] = fw[:, 0]
+    return out

@@ -23,6 +23,7 @@ __all__ = [
     "cache_scan_ref",
     "fused_cache_step",
     "fused_fold",
+    "vary_like",
     "rglru_ref",
     "ssd_ref",
 ]
@@ -213,15 +214,17 @@ class _ScanCache(NamedTuple):
 
 def fused_cache_step(state, page, is_write, noise, hyper, *,
                      epoch_width: int, pred_cap: int, prefetch: bool,
-                     prefetch_width: int):
+                     prefetch_width: int, real=True):
     """One fused request step on duck-typed store state (any pytree with
     the ``StoreState``/``OLState``/``PrefetchState`` fields, ``cache``
     being a :class:`_ScanCache`).
 
     ``noise`` is this step's Random-expert draw (f32[n_lines]) — a row of
     :func:`cache_scan_noise` or an in-loop ``uniform(vkey, ...)``; the PRNG
-    key itself is managed by the caller (left untouched here). Returns
-    ``(state, out)`` with ``out`` matching the reference step's dict."""
+    key itself is managed by the caller (left untouched here). ``real`` is
+    False at padding positions, which must not run WeightAdjust (see
+    ``tiered_store._step``). Returns ``(state, out)`` with ``out``
+    matching the reference step's dict."""
     cache, ols, pf = state.cache, state.ols, state.pf
     t = state.t
     page = page.astype(jnp.int32)
@@ -358,7 +361,7 @@ def fused_cache_step(state, page, is_write, noise, hyper, *,
                           beta=hyper.beta, threshold=hyper.threshold,
                           pred_cap=pred_cap)
     ols = jax.tree.map(
-        lambda new, old: jnp.where(epoch_end & is_ws, new, old),
+        lambda new, old: jnp.where(epoch_end & is_ws & real, new, old),
         _ol.weight_adjust(ols, ol_cfg), ols,
     )
 
@@ -430,6 +433,20 @@ def fused_fold(acc, outs, win, weights, n_windows: int):
     )
 
 
+def vary_like(tree, like):
+    """``tree`` marked varying over every manual mesh axis ``like`` varies
+    over. Under ``shard_map(check_vma=True)`` a scan whose carry starts from
+    a constant (the cold store state) and absorbs sharded requests must
+    start varying; elsewhere this is the identity."""
+    want = jax.typeof(like).vma
+
+    def cast(x):
+        missing = tuple(sorted(want - jax.typeof(x).vma))
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+    return jax.tree.map(cast, tree) if want else tree
+
+
 def cache_scan_ref(state0, acc0, pages, writes, win, hyper, noise, *,
                    epoch_width: int, pred_cap: int, prefetch: bool,
                    prefetch_width: int, n_windows: int, unroll: int = 1,
@@ -490,7 +507,7 @@ def cache_scan_ref(state0, acc0, pages, writes, win, hyper, noise, *,
         new_state, out = fused_cache_step(
             st_in, page, write.astype(bool), nrow, hyper,
             epoch_width=epoch_width, pred_cap=pred_cap, prefetch=prefetch,
-            prefetch_width=prefetch_width,
+            prefetch_width=prefetch_width, real=win_i < n_windows,
         )
         if masked:
             valid = win_i < n_windows
@@ -508,7 +525,8 @@ def cache_scan_ref(state0, acc0, pages, writes, win, hyper, noise, *,
         return new_state, (out, new_state.ols.weights)
 
     xs = (pages, writes, win) if noise is None else (pages, writes, win, noise)
-    final, (outs, wts) = jax.lax.scan(scan_fn, state0, xs, unroll=unroll)
+    final, (outs, wts) = jax.lax.scan(scan_fn, vary_like(state0, pages), xs,
+                                      unroll=unroll)
     fc = final.cache
     final = final._replace(
         ols=final.ols._replace(pred=jnp.concatenate(
@@ -518,7 +536,8 @@ def cache_scan_ref(state0, acc0, pages, writes, win, hyper, noise, *,
         cache=type(cache0)(tags=fc.tags, valid=fc.tags >= 0, dirty=fc.dirty,
                            freq=fc.freq, ts=fc.ts),
     )
-    return final, fused_fold(acc0, outs, win, wts, n_windows)
+    return final, fused_fold(vary_like(acc0, pages), outs, win, wts,
+                             n_windows)
 
 
 def rglru_ref(u, w_a, b_a, w_x, b_x, lam):

@@ -14,33 +14,33 @@ previous-occurrence index ``P`` (``P[j]`` = index of the previous access of
     d_j = #{ k : P[j] < k < j  and  P[k] <= P[j]  and  valid[k] }
 
 (the in-gap positions that are the *first* in-gap occurrence of their
-page). The Pallas kernel tiles this count as a ``[block, block]``
-broadcast-compare per ``(shard, query-block)`` grid cell, looping over
-key blocks up to the query block — O(L^2/2) compares, VPU-friendly, no
-inter-step dependence (contrast the sequential per-request ``lax.scan`` of
-the cache engine). Distances never leak across shard rows (each grid cell
-reads only its own row) or into pad slots (pads output ``-1`` and are
-excluded from every count).
+page). The Pallas kernel tiles this count as ``[128, 128]``
+broadcast-compares per ``(shard, query-block)`` grid cell, streaming key
+chunks from HBM over the range that can hold counted keys — at most
+O(L^2/2) compares, VPU-friendly, no inter-step dependence (contrast the
+sequential per-request ``lax.scan`` of the cache engine). Distances never
+leak across shard rows (each grid cell reads only its own row) or into pad
+slots (pads output ``-1`` and are excluded from every count).
 
-On this CPU container the production entry point :func:`reuse_distances`
-dispatches to the pure-jax fallback (:func:`repro.kernels.ref.
-reuse_distance_ref`, same math, same int32 results — bit-identical); on a
-TPU backend (``REPRO_KERNELS=tpu``) it compiles the Pallas kernel. The
-interpret-mode Pallas path stays testable everywhere
+The production entry point :func:`reuse_distances` runs the Pallas kernel
+where the computation is lowered for a TPU and the pure-jax
+:func:`repro.kernels.ref.reuse_distance_ref` elsewhere (same math, same
+int32 results — bit-identical; :mod:`repro.kernels.backend`). The
+interpret-mode kernel stays testable everywhere
 (``reuse_distance_kernel(..., interpret=True)``).
 """
 from __future__ import annotations
 
 import functools
-import os
-from typing import Optional
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import kernel_or_xla, record_paths
 from repro.kernels.ref import DIST_INF, reuse_distance_ref
 
 __all__ = [
@@ -52,9 +52,11 @@ __all__ = [
     "reset_reuse_compile_count",
 ]
 
-# Mirrors kernels/ops.py: interpret-mode (pure-jax fallback) unless the
-# container bakes a real TPU toolchain.
-INTERPRET = os.environ.get("REPRO_KERNELS", "interpret") != "tpu"
+# Key rows (of 128 positions) per streamed key chunk: 8192 keys, 32 KiB.
+KEY_ROWS = 64
+# Query rows (of 128 positions) per grid cell.
+Q_ROWS = 8
+_BIG = jnp.iinfo(jnp.int32).max
 
 # Trace-time compile counter for the jitted distance engines (both the
 # Pallas wrapper and the ref fallback) — the MRC bench gates on it exactly
@@ -103,100 +105,128 @@ def prev_occurrence(sh_pages: np.ndarray, counts: np.ndarray):
     return prev.reshape(S, L).astype(np.int32), valid
 
 
-def _dominance_kernel(p_ref, v_ref, pt_ref, vt_ref, o_ref, *, block: int):
+def _dominance_kernel(q_ref, k_hbm, o_ref, kbuf, sem, *, key_rows: int,
+                      q_rows: int):
     """One ``(shard, query-block)`` grid cell of the dominance count.
 
-    ``p_ref``/``v_ref`` hold the full shard row (keys); ``pt_ref``/
-    ``vt_ref`` hold this cell's query block as a ``[block, 1]`` column (a
-    host-side transpose, so the kernel needs no in-register transposes).
+    ``q_ref`` holds ``q_rows * 128`` queries lane-dense (``prev``, ``-2`` at
+    pads); one in-register transpose turns each 128-query row into a
+    ``[128, 1]`` column that is compared against ``[1, 128]`` key rows.
+    Keys stay in HBM as ``[chunks, key_rows, 128]`` per shard (``prev``,
+    int32 max at pads) and stream through a two-slot VMEM buffer, one chunk
+    of ``key_rows * 128`` positions per DMA, so VMEM does not grow with the
+    row length. Only chunks that can hold a counted key are fetched: keys
+    after a column's last query, or at or before its smallest ``prev``,
+    never satisfy ``prev[j] < k < j``.
     """
-    jb = pl.program_id(1)
-    j0 = jb * block
-    pj = pt_ref[...]                                     # [block, 1] int32
-    vj = vt_ref[...]                                     # [block, 1] int32
-    jidx = j0 + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    s, jb = pl.program_id(0), pl.program_id(1)
+    kb = key_rows * 128
+    qt = q_ref[...].T                                    # [128, q_rows]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (128, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (128, q_rows), 1)
 
-    def body(kb, acc):
-        k0 = kb * block
-        pk = p_ref[0:1, pl.ds(k0, block)]                # [1, block]
-        vk = v_ref[0:1, pl.ds(k0, block)]                # [1, block]
-        kidx = k0 + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-        m = (
-            (kidx > pj)
-            & (kidx < jidx)
-            & (pk <= pj)
-            & (vk > 0)
-        )
-        return acc + jnp.sum(m.astype(jnp.int32), axis=1, keepdims=True)
+    def fetch(c, slot):
+        return pltpu.make_async_copy(k_hbm.at[s, c], kbuf.at[slot],
+                                     sem.at[slot])
 
-    # Keys at or beyond the query block's end never satisfy k < j: loop
-    # only over the jb+1 key blocks at or before the queries.
-    acc = jax.lax.fori_loop(
-        0, jb + 1, body, jnp.zeros((block, 1), jnp.int32)
-    )
-    out = jnp.where(pj >= 0, acc, DIST_INF)              # first access
-    o_ref[...] = jnp.where(vj > 0, out, -1)              # padding
+    out = jnp.zeros((128, q_rows), jnp.int32)
+    for r in range(q_rows):
+        pj = qt[:, r:r + 1]                              # [128, 1]
+        j0 = (jb * q_rows + r) * 128
+        jidx = j0 + sub
+        lo = jnp.min(jnp.where(pj >= 0, pj, _BIG))
+        c_hi = (j0 + 127) // kb
+        c_lo = jnp.where(lo == _BIG, c_hi + 1, (lo + 1) // kb)
+
+        @pl.when(c_lo <= c_hi)
+        def _():
+            fetch(c_lo, 0).start()
+
+        def chunk(c, acc):
+            slot = (c - c_lo) % 2
+
+            @pl.when(c + 1 <= c_hi)
+            def _():
+                fetch(c + 1, 1 - slot).start()
+
+            fetch(c, slot).wait()
+
+            def row(i, acc):
+                pk = kbuf[slot, pl.ds(i, 1), :]          # [1, 128]
+                kidx = c * kb + i * 128 + lane
+                m = (kidx > pj) & (kidx < jidx) & (pk <= pj)
+                return acc + m.astype(jnp.int32)
+
+            return jax.lax.fori_loop(0, key_rows, row, acc)
+
+        acc = jax.lax.fori_loop(c_lo, c_hi + 1, chunk,
+                                jnp.zeros((128, 128), jnp.int32))
+        d = jnp.sum(acc, axis=1, keepdims=True)
+        d = jnp.where(pj == -2, -1, jnp.where(pj >= 0, d, DIST_INF))
+        out = jnp.where(col == r, d, out)
+    o_ref[...] = out.T
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def reuse_distance_kernel(
     prev: jnp.ndarray,   # int32[S, L] previous-occurrence index (-1 = first)
     valid: jnp.ndarray,  # bool[S, L]  real positions (False = padding)
     *,
-    block: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Pallas dominance-count kernel: int32 ``[S, L]`` reuse distances
     (:data:`DIST_INF` for first accesses, ``-1`` at pad slots). Exact
     integer arithmetic — bit-identical to :func:`repro.kernels.ref.
-    reuse_distance_ref` in both interpret and compiled modes."""
+    reuse_distance_ref`."""
     prev = jnp.asarray(prev, jnp.int32)
-    valid_i = jnp.asarray(valid, jnp.int32)
+    valid = jnp.asarray(valid, bool)
     S, L = prev.shape
-    pad = (-L) % block
-    P = jnp.pad(prev, ((0, 0), (0, pad)), constant_values=-1)
-    V = jnp.pad(valid_i, ((0, 0), (0, pad)), constant_values=0)
-    Lp = L + pad
-    _REUSE_COMPILES[0] += 1  # trace-time: once per XLA compile
-
-    out_t = pl.pallas_call(
-        functools.partial(_dominance_kernel, block=block),
-        grid=(S, Lp // block),
-        in_specs=[
-            pl.BlockSpec((1, Lp), lambda s, jb: (s, 0)),      # keys P
-            pl.BlockSpec((1, Lp), lambda s, jb: (s, 0)),      # keys valid
-            pl.BlockSpec((block, 1), lambda s, jb: (jb, s)),  # queries P^T
-            pl.BlockSpec((block, 1), lambda s, jb: (jb, s)),  # queries V^T
+    key_rows = min(KEY_ROWS, pl.next_power_of_2(-(-L // 128)))
+    q_rows = min(Q_ROWS, key_rows)
+    kb = key_rows * 128
+    Lp = -(-L // kb) * kb
+    pad = ((0, 0), (0, Lp - L))
+    queries = jnp.pad(jnp.where(valid, prev, -2), pad, constant_values=-2)
+    keys = jnp.pad(jnp.where(valid, prev, _BIG), pad, constant_values=_BIG)
+    rows = pl.BlockSpec((None, q_rows, 128), lambda s, jb: (s, jb, 0))
+    out = pl.pallas_call(
+        functools.partial(_dominance_kernel, key_rows=key_rows,
+                          q_rows=q_rows),
+        grid=(S, Lp // (q_rows * 128)),
+        in_specs=[rows, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct((S, Lp // 128, 128), jnp.int32),
+        scratch_shapes=[
+            pltpu.VMEM((2, key_rows, 128), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
-        out_specs=pl.BlockSpec((block, 1), lambda s, jb: (jb, s)),
-        out_shape=jax.ShapeDtypeStruct((Lp, S), jnp.int32),
-        interpret=interpret,
-    )(P, V, P.T, V.T)
-    return out_t.T[:, :L]
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="reuse_distance",
+    )(queries.reshape(S, Lp // 128, 128),
+      keys.reshape(S, Lp // kb, key_rows, 128))
+    return out.reshape(S, Lp)[:, :L]
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
-def _ref_engine(prev, valid, *, block: int = 128):
+def _engine(prev, valid, *, block: int):
     _REUSE_COMPILES[0] += 1  # trace-time: once per XLA compile
-    return reuse_distance_ref(prev, valid, block=block)
+    return kernel_or_xla(
+        reuse_distance_kernel,
+        functools.partial(reuse_distance_ref, block=block), prev, valid)
 
 
-def reuse_distances(
-    prev: np.ndarray,
-    valid: np.ndarray,
-    *,
-    block: int = 128,
-    interpret: Optional[bool] = None,
-) -> jnp.ndarray:
-    """Production entry point: Pallas kernel on a TPU backend, pure-jax
-    :func:`~repro.kernels.ref.reuse_distance_ref` fallback on CPU (same
-    int32 results, bit-identical). ``interpret=None`` follows the
-    ``REPRO_KERNELS`` convention of :mod:`repro.kernels.ops`."""
-    if interpret is None:
-        interpret = INTERPRET
-    if interpret:
-        return _ref_engine(jnp.asarray(prev, jnp.int32),
-                           jnp.asarray(valid, bool), block=block)
-    return reuse_distance_kernel(jnp.asarray(prev, jnp.int32),
-                                 jnp.asarray(valid, bool),
-                                 block=block, interpret=False)
+def reuse_distances(prev: np.ndarray, valid: np.ndarray, *,
+                    block: int = 128) -> jnp.ndarray:
+    """Production entry point: reuse distances ``[S, L]`` from the Pallas
+    kernel where the computation is lowered for a TPU, from
+    :func:`~repro.kernels.ref.reuse_distance_ref` (query blocks of
+    ``block``) elsewhere — same int32 results, see
+    :mod:`repro.kernels.backend`. Counts the path that ran under
+    ``"reuse_distance"``."""
+    dist, path = _engine(jnp.asarray(prev, jnp.int32),
+                         jnp.asarray(valid, bool), block=block)
+    record_paths("reuse_distance", path)
+    return dist

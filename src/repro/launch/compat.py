@@ -1,42 +1,22 @@
-"""Version-agnostic jax SPMD compat shims.
+"""JAX environment shims shared by the entry points and the sweep engine.
 
-Small, dependency-free home for the cross-version wrappers used by both the
+Small, dependency-free home for the SPMD helpers used by both the
 heavyweight launch layer (:mod:`repro.launch.spmd`) and light consumers like
 the sweep engine (:mod:`repro.sim.sweep`), which must not drag the model /
-training stack into their import graph.
+training stack into their import graph, plus the one helper that places
+JAX's persistent compilation cache for the entry points.
 """
 from __future__ import annotations
+
+import os
 
 import numpy as np
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh
 
-try:  # jax >= 0.6: top-level export, replication check spelled check_vma
-    from jax import shard_map as _shard_map
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax <= 0.4.x: experimental module, kwarg is check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-__all__ = ["shard_map", "device_mesh"]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-agnostic shard_map: translates ``check_vma`` to the kwarg the
-    installed jax understands. Pre-vma jax's ``check_rep`` inference cannot
-    prove replication through our psum/all_gather patterns (it rejects specs
-    the vma system accepts), so there the check is disabled outright."""
-    check = check_vma if _CHECK_KW == "check_vma" else False
-    return _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        **{_CHECK_KW: check},
-    )
+__all__ = ["shard_map", "device_mesh", "use_compile_cache"]
 
 
 def device_mesh(axis_name: str, devices=None) -> Mesh:
@@ -45,3 +25,18 @@ def device_mesh(axis_name: str, devices=None) -> Mesh:
     mesh size even under multi-process jax)."""
     devs = list(jax.local_devices() if devices is None else devices)
     return Mesh(np.asarray(devs), (axis_name,))
+
+
+def use_compile_cache(checkout: str) -> str:
+    """Point JAX's persistent compilation cache at a fixed directory and
+    return it. ``JAX_COMPILATION_CACHE_DIR``, when set, is honoured as JAX
+    reads it and nothing else is configured; otherwise the cache lives at
+    ``<checkout>/.jax_cache`` (a fixed path: the directory is part of the
+    cache key, so one that moves between runs never hits). Called by the
+    entry points only — importing the library configures nothing."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(os.path.abspath(checkout), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

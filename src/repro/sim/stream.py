@@ -52,6 +52,7 @@ import jax
 
 from repro.core.queuing import transient_two_tier
 from repro.core.traffic import TenantStream
+from repro.kernels.backend import XLA, record_paths
 from repro.sim.engine import (
     SimReport,
     TenantCounters,
@@ -286,6 +287,8 @@ def stream_tier1_counters(
         # deliberately-synchronous naive baseline.
         dev = jax.device_put((sh_p, sh_w, sh_win))
         carry = eng(hyper, carry, *dev)
+        # The resumable chunk mode has one implementation, the XLA engine.
+        record_paths("cache_scan", np.full(n_shards, XLA))
         tc2 = perf_counter()
         if not donate:
             jax.block_until_ready(carry)

@@ -83,6 +83,7 @@ from jax.sharding import PartitionSpec
 
 from repro.core.queuing import fluid_compile_count, reset_fluid_compile_count
 from repro.core.traffic import make_stream, make_timed_stream
+from repro.kernels.backend import record_paths
 from repro.launch.compat import device_mesh, shard_map
 from repro.sim.engine import (
     SimReport,
@@ -102,7 +103,7 @@ from repro.storage.tiered_store import (
     StoreConfig,
     StoreHyper,
     partition_streams,
-    run_stream,
+    run_stream_path,
     timestamp_window_ids,
 )
 
@@ -313,13 +314,14 @@ def _stack_hypers(stores: Sequence[StoreConfig]) -> StoreHyper:
 
 
 def _batched_engine(
-    store: StoreConfig, unroll: int, n_dev: int, n_windows: int,
+    store: StoreConfig, unroll: int, devices: tuple, n_windows: int,
     engine: str = "fused", donate: bool = True,
 ) -> Callable:
     """The one-compile megabatch engine for a structural store config:
     ``(hyper [N], pages [N, S, L], writes [N, S, L], win [N, S, L]) ->
-    StreamStats [N, S]`` (windowed counters ``[N, S, n_windows]``), point
-    axis sharded over all local devices. Wall-clock specs feed the same
+    (StreamStats [N, S], engine path ids [N, S])`` (windowed counters
+    ``[N, S, n_windows]``), point
+    axis sharded over ``devices``. Wall-clock specs feed the same
     ``win`` operand (arrival times become int32 ids host-side), so timed
     and request-index grids share this one engine. Cached so repeated
     sweeps reuse both the wrapper and jit's compile cache.
@@ -330,7 +332,7 @@ def _batched_engine(
     (``donate_argnums``) so XLA may recycle their allocations while the
     engine runs — ``donate=False`` keeps the undonated baseline
     available (buffers stay valid after the call)."""
-    key = (store, unroll, n_dev, n_windows, engine, donate)
+    key = (store, unroll, devices, n_windows, engine, donate)
     fn = _ENGINE_CACHE.get(key)
     if fn is not None:
         return fn
@@ -340,7 +342,7 @@ def _batched_engine(
 
         def point(h, p, w, wi):
             return jax.vmap(
-                lambda pp, ww, wwi: run_stream(
+                lambda pp, ww, wwi: run_stream_path(
                     store, pp, ww, hyper=h, unroll=unroll,
                     n_windows=n_windows, window_ids=wwi, engine=engine,
                 )
@@ -349,11 +351,11 @@ def _batched_engine(
         return jax.vmap(point)(hyper, sh_pages, sh_writes, sh_win)
     n_in = 4
 
-    if n_dev > 1:
+    if len(devices) > 1:
         spec = PartitionSpec("points")
         jfn = jax.jit(shard_map(
             body,
-            mesh=device_mesh("points"),
+            mesh=device_mesh("points", devices),
             in_specs=(spec,) * n_in,
             out_specs=spec,
             check_vma=True,
@@ -399,10 +401,13 @@ class _PendingBucket:
     counts: list         # per-point per-shard real request counts
     writes: list         # per-point per-shard write counts
     cap: int             # padded stream length (bucket)
-    stats: object        # StreamStats of device arrays (async futures)
+    stats: object        # (StreamStats, engine path ids) of device arrays
+                         # (async futures)
 
     def gather(self) -> dict:
-        stacked = jax.tree.map(np.asarray, self.stats)  # blocks on device
+        # Blocks on the device.
+        stacked, paths = jax.tree.map(np.asarray, self.stats)
+        record_paths("cache_scan", paths[:len(self.sigs)])
         out = {}
         for i, sig in enumerate(self.sigs):
             stats_i = jax.tree.map(lambda a: a[i], stacked)
@@ -413,7 +418,7 @@ class _PendingBucket:
 
 
 def _dispatch_group(
-    specs: list[SimSpec], sigs: list, *, unroll: int,
+    specs: list[SimSpec], sigs: list, *, unroll: int, devices: tuple,
     engine: str = "fused", donate: bool = True,
     _prof: Optional[dict] = None,
 ) -> list[_PendingBucket]:
@@ -426,7 +431,7 @@ def _dispatch_group(
     n_shards = specs[0].n_shards
     n_windows, window_dt0 = specs[0].window_grid()
     timed = window_dt0 is not None
-    n_dev = jax.local_device_count()
+    n_dev = len(devices)
 
     t0 = perf_counter()
     members = []
@@ -500,15 +505,19 @@ def _dispatch_group(
         stores += [stores[0]] * (n_pad - n)
         hyper = _stack_hypers(stores)
 
-        eng = _batched_engine(store_static, unroll, n_dev, n_windows,
+        eng = _batched_engine(store_static, unroll, devices, n_windows,
                               engine, donate)
         log.info(
             "sweep: dispatch %d points x %d shards @ len %d "
             "(n_lines=%d, windows=%d, timed=%s, devices=%d)",
             n, n_shards, cap, store_static.n_lines, n_windows, timed, n_dev,
         )
-        stats = eng(hyper, jnp.asarray(sh_pages),
-                    jnp.asarray(sh_writes), jnp.asarray(sh_win))
+        operands = (sh_pages, sh_writes, sh_win)
+        if n_dev == 1:
+            # One device: place the operands there; the engine follows.
+            operands = jax.device_put(operands, devices[0])
+            hyper = jax.device_put(hyper, devices[0])
+        stats = eng(hyper, *operands)
         pending.append(_PendingBucket(
             sigs=[m.sig for m in group],
             counts=[m.counts for m in group],
@@ -542,6 +551,7 @@ def sweep(
     donate: bool = True,
     profile: bool = False,
     verbose: bool = False,
+    devices: Optional[Sequence] = None,
 ) -> SweepResult:
     """Evaluate ``base`` at every point of the ``axes`` grid.
 
@@ -578,10 +588,17 @@ def sweep(
 
     ``engine`` selects the tier-1 request-loop implementation
     (:func:`repro.storage.tiered_store.run_stream`): ``"fused"`` (default)
-    is the fused cache-scan engine, ``"scan"`` the original per-step
-    reference it is bit-exact against. ``donate=True`` donates the stacked
+    is the fused cache-scan engine, ``"pallas"`` the same with its Pallas
+    kernel where the computation is lowered for a TPU, ``"scan"`` the
+    original per-step reference both are bit-exact against. ``donate=True``
+    donates the stacked
     stream buffers to each megabatch dispatch (``donate_argnums``);
     ``donate=False`` keeps the undonated baseline.
+
+    ``devices`` are the devices the megabatch's point axis is sharded
+    over (default: every local device); one device runs it unsharded
+    there. The routed stream/MRC paths and the report stage run on the
+    default device.
 
     ``profile=True`` attaches a per-stage wall-clock breakdown (stream
     gen / engine dispatch / report solve / assembly, seconds) to
@@ -620,6 +637,7 @@ def sweep(
         axes_dict = {}
         points = [dict(pt) for pt in axes]
     specs = [base.replace(**pt) for pt in points]
+    devices = tuple(jax.local_devices() if devices is None else devices)
     solver = ("batched" if batch else "scalar") if report == "auto" else report
     prof: Optional[dict] = (
         {"stream_gen": 0.0, "engine_dispatch": 0.0,
@@ -666,8 +684,8 @@ def sweep(
             )
             pending.extend(
                 _dispatch_group([unique[s] for s in sigs], sigs,
-                                unroll=unroll, engine=engine,
-                                donate=donate, _prof=prof)
+                                unroll=unroll, devices=devices,
+                                engine=engine, donate=donate, _prof=prof)
             )
         t0 = perf_counter()
         for bucket in pending:

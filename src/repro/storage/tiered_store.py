@@ -55,7 +55,9 @@ import numpy as np
 from repro.core import online_learning as ol
 from repro.core import prefetch as pfm
 from repro.core.mapping import page_to_shard
+from repro.kernels.backend import XLA, record_paths
 from repro.kernels.cache_scan import fused_cache_scan
+from repro.kernels.ref import cache_scan_ref, vary_like
 from repro.storage.cache_state import CacheState, init_cache
 
 __all__ = [
@@ -64,6 +66,7 @@ __all__ = [
     "StoreState",
     "StreamStats",
     "run_stream",
+    "run_stream_path",
     "run_stream_chunked",
     "run_distributed",
     "partition_streams",
@@ -83,6 +86,15 @@ __all__ = [
 WS_POLICY_IDX = -1
 POLICY_TO_IDX = {"ws": WS_POLICY_IDX,
                  **{name: i for i, name in enumerate(ol.EXPERTS)}}
+
+# Request-loop implementations (see run_stream).
+ENGINES = ("fused", "pallas", "scan")
+
+
+def _check_engine(engine: str) -> None:
+    if engine not in ENGINES:
+        raise ValueError(
+            f"unknown engine {engine!r}; options: {', '.join(ENGINES)}")
 
 
 class StoreHyper(NamedTuple):
@@ -217,10 +229,12 @@ def init_store(cfg: StoreConfig, seed: int = 0) -> StoreState:
     )
 
 
-def _step(cfg: StoreConfig, hyper: StoreHyper, state: StoreState, req):
+def _step(cfg: StoreConfig, hyper: StoreHyper, state: StoreState, req,
+          real=True):
     # ``cfg`` carries only structural knobs here (shapes, scan layout,
     # prefetcher wiring); the scalar learning knobs come from ``hyper`` so
-    # they may be traced (one compile serves a grid of settings).
+    # they may be traced (one compile serves a grid of settings). ``real``
+    # is False at padding positions (window id == n_windows).
     ol_cfg = ol.OLConfig(
         epoch_width=cfg.epoch_width,
         alpha=hyper.alpha,
@@ -304,12 +318,16 @@ def _step(cfg: StoreConfig, hyper: StoreHyper, state: StoreState, req):
     # --- 5. epoch boundary -------------------------------------------------
     # WeightAdjust fires only for the weight-sharing policy (policy_idx < 0);
     # fixed-expert baselines keep their initial weights, exactly as when the
-    # policy was a compile-time constant.
+    # policy was a compile-time constant. Padding never fires it: pads are
+    # pure hits that leave the learner state alone, but an epoch boundary
+    # among them would renormalize unchanged weights, which is not
+    # idempotent in f32 — so final_weights would depend on the pad length.
     epoch_end = (t + 1) % cfg.epoch_width == 0
     is_ws = hyper.policy_idx < 0
     ols_adj = ol.weight_adjust(ols, ol_cfg)
     ols = jax.tree.map(
-        lambda new, old: jnp.where(epoch_end & is_ws, new, old), ols_adj, ols
+        lambda new, old: jnp.where(epoch_end & is_ws & real, new, old),
+        ols_adj, ols,
     )
 
     out = dict(
@@ -393,7 +411,14 @@ def _fold(acc: _Accum, out: dict, win: jnp.ndarray,
         win_evictions=acc.win_evictions.at[win].add(ev, mode="drop"),
         win_expert_use=acc.win_expert_use.at[win, expert].add(ev,
                                                               mode="drop"),
-        win_weights=acc.win_weights.at[win].set(weights, mode="drop"),
+        # A select, not a scatter-set: compiled for a TPU v5e, the scatter-set
+        # left all-zero weight rows for the last 46 points of a
+        # [288, 4, 8192] megabatch, with buffer donation on and off, while
+        # every scatter-add counter was right (repro: PERF.md, open
+        # questions).
+        win_weights=jnp.where(
+            (jnp.arange(acc.win_weights.shape[0]) == win)[:, None],
+            weights, acc.win_weights),
     )
 
 
@@ -457,12 +482,15 @@ def run_stream(
     (semantics-preserving; larger values trade compile time for fewer loop
     iterations on wide batches).
 
-    ``engine`` selects the request-loop implementation: ``"fused"`` (the
-    default) routes through :func:`repro.kernels.cache_scan.fused_cache_scan`
-    — one-hot elementwise state updates with hoisted Random-expert draws,
-    VMEM-resident Pallas kernel on TPU backends — and ``"scan"`` keeps the
-    original per-step gather/scatter ``lax.scan``, the golden reference the
-    fused engine is bit-exact against.
+    ``engine`` selects the request-loop implementation (one of
+    :data:`ENGINES`): ``"fused"`` (the default) routes through
+    :func:`repro.kernels.cache_scan.fused_cache_scan` — one-hot elementwise
+    state updates with hoisted Random-expert draws, the XLA engine on every
+    platform; ``"pallas"`` is the same call with the VMEM-resident Pallas
+    kernel where the computation is lowered for a TPU (the engine-path rule
+    in :mod:`repro.kernels.cache_scan`); ``"scan"`` keeps the original
+    per-step gather/scatter ``lax.scan``, the golden reference both are
+    bit-exact against.
 
     ``n_windows`` resolves the counters over time windows (carried
     accumulators — O(n_windows) memory, no per-request outputs). The window
@@ -479,6 +507,30 @@ def run_stream(
       windowed counters);
     - by default, equal request-count slices of this stream's own length.
     """
+    return run_stream_path(
+        cfg, pages, is_write, seed=seed, hyper=hyper, unroll=unroll,
+        n_windows=n_windows, window_ids=window_ids, timestamps=timestamps,
+        window_dt=window_dt, engine=engine)[0]
+
+
+def run_stream_path(
+    cfg: StoreConfig,
+    pages: jnp.ndarray,
+    is_write: jnp.ndarray,
+    *,
+    seed: int = 0,
+    hyper: Optional[StoreHyper] = None,
+    unroll: int = 1,
+    n_windows: int = 1,
+    window_ids: Optional[jnp.ndarray] = None,
+    timestamps: Optional[jnp.ndarray] = None,
+    window_dt=None,
+    engine: str = "fused",
+):
+    """:func:`run_stream` plus the engine that ran the request loop:
+    ``(stats, path)``, ``path`` the int32 :data:`~repro.kernels.backend.
+    PALLAS` / ``XLA`` id, which callers count with
+    :func:`~repro.kernels.backend.record_paths`."""
     pages = jnp.asarray(pages, jnp.int32)
     is_write = jnp.asarray(is_write, bool)
     if hyper is None:
@@ -494,25 +546,27 @@ def run_stream(
     elif window_ids is None:
         window_ids = stream_window_ids(pages.shape[0], n_windows)
     window_ids = jnp.asarray(window_ids, jnp.int32)
-    if engine not in ("fused", "scan"):
-        raise ValueError(f"unknown engine {engine!r}; options: fused, scan")
+    _check_engine(engine)
 
     carry0 = (init_store(cfg, seed), _init_accum(n_windows))
-    if engine == "fused":
-        final, acc = fused_cache_scan(
+    if engine != "scan":
+        weights, acc, path = fused_cache_scan(
             cfg, hyper, carry0[0], carry0[1], pages, is_write, window_ids,
-            n_windows=n_windows, unroll=unroll,
+            n_windows=n_windows, unroll=unroll, pallas=engine == "pallas",
         )
     else:
         def scan_fn(carry, req):
             state, acc = carry
             page, write, win = req
-            state, out = _step(cfg, hyper, state, (page, write))
+            state, out = _step(cfg, hyper, state, (page, write),
+                               real=win < n_windows)
             return (state, _fold(acc, out, win, state.ols.weights)), None
 
         (final, acc), _ = jax.lax.scan(
-            scan_fn, carry0, (pages, is_write, window_ids), unroll=unroll
+            scan_fn, vary_like(carry0, pages), (pages, is_write, window_ids),
+            unroll=unroll,
         )
+        weights, path = final.ols.weights, jnp.int32(XLA)
     return StreamStats(
         requests=pages.shape[0] + jnp.zeros((), jnp.int32),
         hits=acc.hits,
@@ -522,7 +576,7 @@ def run_stream(
         tier2_writes=acc.tier2_writes,
         evictions=acc.evictions,
         expert_use=acc.expert_use,
-        final_weights=final.ols.weights,
+        final_weights=weights,
         win_requests=acc.win_requests,
         win_hits=acc.win_hits,
         win_misses=acc.win_misses,
@@ -532,7 +586,7 @@ def run_stream(
         win_evictions=acc.win_evictions,
         win_expert_use=acc.win_expert_use,
         win_weights=acc.win_weights,
-    )
+    ), path
 
 
 run_stream_jit = jax.jit(
@@ -742,12 +796,13 @@ def run_distributed(
             pages, is_write, n_shards=n_shards, mapping=mapping,
             n_pages=n_pages, n_windows=n_windows, owner=owner,
         )
-    stats = jax.vmap(
-        lambda p, w, wi: run_stream(
+    stats, paths = jax.vmap(
+        lambda p, w, wi: run_stream_path(
             cfg, p, w, seed=seed, n_windows=n_windows, window_ids=wi,
             engine=engine,
         )
     )(jnp.asarray(sh_pages), jnp.asarray(sh_writes), jnp.asarray(sh_win))
+    record_paths("cache_scan", paths)
     return correct_padded_stats(stats, counts, sh_pages.shape[1]), counts
 
 
@@ -813,9 +868,11 @@ def stream_chunk_engine(cfg: StoreConfig, *, unroll: int = 1,
     compare against. ``engine`` selects the fused one-hot request loop
     (default) or the original ``"scan"`` reference (see
     :func:`run_stream`); both are bit-exact, masked-pad semantics
-    included."""
-    if engine not in ("fused", "scan"):
-        raise ValueError(f"unknown engine {engine!r}; options: fused, scan")
+    included. The chunk mode has no Pallas kernel, so ``"pallas"`` runs
+    the fused XLA loop here."""
+    _check_engine(engine)
+    if engine == "pallas":
+        engine = "fused"
     static = cfg.static_config()
     key = (static, unroll, n_windows, donate, engine)
     fn = _STREAM_CACHE.get(key)
@@ -827,11 +884,15 @@ def stream_chunk_engine(cfg: StoreConfig, *, unroll: int = 1,
 
         def shard(state, acc, p, w, wi):
             if engine == "fused":
-                # Resumable masked mode: pads leave the carried state
-                # (PRNG key included) untouched; the PRNG stays in-loop
-                # because the carried key must advance per real request.
-                return fused_cache_scan(
-                    static, hyper, state, acc, p, w, wi,
+                # Resumable masked mode, always the XLA engine: pads leave
+                # the carried state (PRNG key included) untouched; the PRNG
+                # stays in-loop because the carried key must advance per
+                # real request.
+                return cache_scan_ref(
+                    state, acc, p, w, wi, hyper, None,
+                    epoch_width=static.epoch_width,
+                    pred_cap=static.pred_cap, prefetch=static.prefetch,
+                    prefetch_width=static.prefetch_width,
                     n_windows=n_windows, unroll=unroll, masked=True)
 
             def scan_fn(c, req):
@@ -839,7 +900,7 @@ def stream_chunk_engine(cfg: StoreConfig, *, unroll: int = 1,
                 page, write, win_i = req
                 valid = win_i < n_windows
                 new_state, out = _step(static, hyper, state,
-                                       (page, write))
+                                       (page, write), real=valid)
                 # Masked step: padding leaves the state (including t and
                 # the PRNG key) untouched and contributes nothing to the
                 # scalar totals; the windowed scatters drop pad ids on
@@ -933,9 +994,7 @@ def run_stream_chunked(
     """Single-shard chunked replay: :func:`run_stream` semantics, consumed
     ``chunk`` requests at a time through the resumable chunk engine.
     Bit-identical to ``run_stream(cfg, pages, is_write, ...)`` for every
-    counter (``final_weights`` may differ only when that one-shot call was
-    itself padded — pads there keep running epoch boundaries after the last
-    real request; no counter reads the difference). The multi-shard,
+    counter and ``final_weights``. The multi-shard,
     generator-fed production path is :func:`repro.sim.stream.simulate_stream`."""
     if chunk < 1:
         raise ValueError("chunk must be >= 1")

@@ -23,6 +23,7 @@ import pytest
 
 import jax
 
+from repro.kernels import reuse_distance
 from repro.kernels.ref import DIST_INF, reuse_distance_ref
 from repro.kernels.reuse_distance import (
     prev_occurrence,
@@ -94,22 +95,56 @@ def test_ref_matches_bruteforce():
 @pytest.mark.parametrize("seed,S,L,block", [(2, 1, 16, 8), (3, 4, 100, 16),
                                             (4, 2, 128, 128), (5, 3, 37, 32)])
 def test_pallas_interpret_matches_ref(seed, S, L, block):
-    """Golden: interpret-mode Pallas kernel == pure-jax oracle, bit for
-    bit, across shapes that exercise padding and multi-block loops."""
+    """Golden: interpret-mode Pallas kernel == pure-jax oracle (query blocks
+    of ``block``), bit for bit, across shapes that exercise padding."""
     rng = np.random.default_rng(seed)
     sh_pages, counts = _ragged_prev(rng, S=S, L=L, n_pages=11)
     prev, valid = prev_occurrence(sh_pages, counts)
     ref = np.asarray(reuse_distance_ref(prev, valid, block=block))
     ker = np.asarray(
-        reuse_distance_kernel(prev, valid, block=block, interpret=True))
+        reuse_distance_kernel(prev, valid, interpret=True))
+    np.testing.assert_array_equal(ker, ref)
+
+
+def _kernel_with_chunks(monkeypatch, key_rows, q_rows, prev, valid, *,
+                        interpret):
+    """Run the kernel with ``key_rows``-row key chunks and ``q_rows``-row
+    query blocks; the traces made with them are dropped afterwards."""
+    monkeypatch.setattr(reuse_distance, "KEY_ROWS", key_rows)
+    monkeypatch.setattr(reuse_distance, "Q_ROWS", q_rows)
+    jax.clear_caches()
+    try:
+        return np.asarray(
+            reuse_distance_kernel(prev, valid, interpret=interpret))
+    finally:
+        jax.clear_caches()
+
+
+@pytest.mark.parametrize("seed,S,L,n_pages,key_rows,q_rows", [
+    (7, 2, 300, 11, 1, 1),      # 3 key chunks of 128, 3 query cells
+    (8, 3, 700, 200, 2, 1),     # long reuse gaps span several chunks
+    (9, 1, 1000, 40, 4, 2),     # multi-row chunks and query blocks
+])
+def test_pallas_interpret_multi_chunk_matches_ref(monkeypatch, seed, S, L,
+                                                  n_pages, key_rows, q_rows):
+    """Golden with small key chunks: each row streams several key chunks
+    through both buffer slots, skips the chunks before a column's smallest
+    ``prev`` and spans several query-block grid cells."""
+    rng = np.random.default_rng(seed)
+    sh_pages, counts = _ragged_prev(rng, S=S, L=L, n_pages=n_pages)
+    prev, valid = prev_occurrence(sh_pages, counts)
+    ref = np.asarray(reuse_distance_ref(prev, valid))
+    ker = _kernel_with_chunks(monkeypatch, key_rows, q_rows, prev, valid,
+                              interpret=True)
     np.testing.assert_array_equal(ker, ref)
 
 
 @pytest.mark.kernels
-def test_pallas_compiled_matches_ref():
+def test_pallas_compiled_matches_ref(monkeypatch):
     """Compiled-mode golden — only meaningful on an accelerator backend
     (deselect with ``-m 'not kernels'``; auto-skips on CPU, where
-    non-interpret Pallas does not lower)."""
+    non-interpret Pallas does not lower). The second case streams three
+    key chunks of 1,024 positions over three query cells per row."""
     if jax.default_backend() == "cpu":
         pytest.skip("no accelerator backend: compiled Pallas needs TPU/GPU")
     rng = np.random.default_rng(6)
@@ -118,6 +153,12 @@ def test_pallas_compiled_matches_ref():
     ref = np.asarray(reuse_distance_ref(prev, valid))
     ker = np.asarray(
         reuse_distance_kernel(prev, valid, interpret=False))
+    np.testing.assert_array_equal(ker, ref)
+    sh_pages, counts = _ragged_prev(rng, S=3, L=3000, n_pages=500)
+    prev, valid = prev_occurrence(sh_pages, counts)
+    ref = np.asarray(reuse_distance_ref(prev, valid))
+    ker = _kernel_with_chunks(monkeypatch, 8, 8, prev, valid,
+                              interpret=False)
     np.testing.assert_array_equal(ker, ref)
 
 
@@ -140,7 +181,7 @@ def test_shard_segmentation_no_leaks():
     np.testing.assert_array_equal(d[:, 4:], -1)
     # Interpret-mode kernel agrees on the same segmentation case.
     ker = np.asarray(
-        reuse_distance_kernel(prev, valid, block=4, interpret=True))
+        reuse_distance_kernel(prev, valid, interpret=True))
     np.testing.assert_array_equal(ker, d)
 
 

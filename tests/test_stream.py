@@ -76,8 +76,6 @@ class TestRunStreamChunked:
             got = run_stream_chunked(cfg, pages, writes, chunk=chunk,
                                      n_windows=5)
             for f in ref._fields:
-                if f == "final_weights":
-                    continue  # one-shot pads keep running epoch boundaries
                 np.testing.assert_array_equal(
                     np.asarray(getattr(ref, f)), np.asarray(getattr(got, f)),
                     err_msg=f"{policy} chunk={chunk}: {f}")
