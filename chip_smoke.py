@@ -236,7 +236,8 @@ def _run_sweep(base, axes, devices, kind, label, **kw):
     say(f"  {label} [{kind} x{len(devices)}]: wall {c.wall:.3f} s, compile "
         f"{c.compile:.3f} s, warm {c.warm:.3f} s -> "
         f"{len(res.reports) / c.warm:.2f} points/s; stages: stream_gen "
-        f"{p['stream_gen']:.3f} s, engine {p['engine_dispatch']:.3f} s "
+        f"{p['stream_gen']:.3f} s, engine "
+        f"{p['engine_dispatch_submit'] + p['engine_dispatch_wait']:.3f} s "
         f"(submit {p['engine_dispatch_submit']:.3f} s, wait "
         f"{p['engine_dispatch_wait']:.3f} s), report_solve "
         f"{p['report_solve']:.3f} s, assembly {p['assembly']:.3f} s")

@@ -65,8 +65,6 @@ from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
-from time import perf_counter
-
 from repro.core.mapping import apply_failover, page_to_shard
 from repro.core.queuing import (
     FluidReport,
@@ -80,6 +78,7 @@ from repro.core.queuing import (
     transient_two_tier,
 )
 from repro.core.traffic import make_stream, make_timed_stream
+from repro.sim.spans import span
 from repro.sim.spec import ResolvedRates, SimSpec
 from repro.storage.tiered_store import correct_padded_stats, run_distributed
 import jax.numpy as jnp
@@ -984,7 +983,8 @@ def batched_reports(
     the kernel per batch shape); a fixed grouping is deterministic.
 
     ``_prof`` (internal, used by ``sweep(profile=True)``): a dict that
-    accumulates ``report_solve`` / ``assembly`` stage seconds.
+    collects the ``report_solve`` / ``assembly`` stage spans
+    (:func:`repro.sim.spans.span`).
     """
     if solver not in ("batched", "scalar"):
         raise ValueError(
@@ -1000,90 +1000,83 @@ def batched_reports(
         key = _report_group_key(prep) if solver == "batched" else None
         groups.setdefault(key, []).append(i)
 
-    solve_s = 0.0
-    asm_s = 0.0
     reports: list = [None] * len(preps)
     for key, idxs in groups.items():
         if key is None:
             for i in idxs:
                 prep = preps[i]
-                t0 = perf_counter()
-                sh_tr = transient_two_tier(
-                    prep.lam_sw, prep.p12_sw, prep.sh_mu1, prep.sh_mu2,
-                    **prep.tr_kw)
-                transient = transient_two_tier(
-                    prep.pool_lam, prep.pool_p12, prep.pool_mu1,
-                    prep.pool_mu2, **prep.tr_kw)
-                eq = _point_equilibrium(prep)
-                t1 = perf_counter()
-                reports[i] = _finish_report(prep, eq, sh_tr, transient,
-                                            _onsets(sh_tr, transient))
-                t2 = perf_counter()
-                solve_s += t1 - t0
-                asm_s += t2 - t1
+                with span("report_solve", _prof):
+                    sh_tr = transient_two_tier(
+                        prep.lam_sw, prep.p12_sw, prep.sh_mu1, prep.sh_mu2,
+                        **prep.tr_kw)
+                    transient = transient_two_tier(
+                        prep.pool_lam, prep.pool_p12, prep.pool_mu1,
+                        prep.pool_mu2, **prep.tr_kw)
+                    eq = _point_equilibrium(prep)
+                with span("assembly", _prof):
+                    reports[i] = _finish_report(prep, eq, sh_tr, transient,
+                                                _onsets(sh_tr, transient))
             continue
 
         group = [preps[i] for i in idxs]
         p0 = group[0]
         full = np.shape(p0.lam_sw)          # [S, W]
-        t0 = perf_counter()
-        kw = {k: v for k, v in p0.tr_kw.items() if k not in ("mode", "dt")}
-        # Stacked per-shard solve: [P, S, W].
-        sh_tr_b = fluid_two_tier_batched(
-            np.stack([p.lam_sw for p in group]),
-            np.stack([p.p12_sw for p in group]),
-            np.stack([np.broadcast_to(p.sh_mu1, full) for p in group]),
-            np.stack([np.broadcast_to(p.sh_mu2, full) for p in group]),
-            dt=p0.duration, **kw)
-        # Stacked pooled solve: [P, W].
-        tr_b = fluid_two_tier_batched(
-            np.stack([p.pool_lam for p in group]),
-            np.stack([p.pool_p12 for p in group]),
-            np.stack([np.broadcast_to(np.asarray(p.pool_mu1, float),
-                                      full[-1:]) for p in group]),
-            np.stack([np.broadcast_to(np.asarray(p.pool_mu2, float),
-                                      full[-1:]) for p in group]),
-            dt=p0.duration, **kw)
-        # Onset scans once over the whole stack (satellite of the batched
-        # pipeline: these used to re-run per report).
-        sh_onsets_b = np.asarray(sh_tr_b.onset())            # [P, S]
-        pooled_onset_b = np.asarray(tr_b.onset())            # [P]
-        sh_meta_b = (np.asarray(sh_tr_b.metastable_onset())
-                     if sh_tr_b.metastable is not None else None)
-        pooled_meta_b = (np.asarray(tr_b.metastable_onset())
-                         if tr_b.metastable is not None else None)
-        # Stationary solves for the whole group: [P, S] + [P].
-        eq_b = _solve_equilibrium(
-            np.stack([np.full(p.spec.n_shards, p.spec.lam, float)
-                      for p in group]),
-            np.stack([p.mu1_v for p in group]),
-            np.stack([p.mu2_v for p in group]),
-            np.stack([p.p12_sh for p in group]),
-            np.asarray([p.spec.lam for p in group], float),
-            np.asarray([p.rates.mu1 for p in group], float),
-            np.asarray([p.rates.mu2 for p in group], float),
-            np.asarray([p.p12 for p in group], float),
-            k=p0.spec.k_servers, flow=p0.spec.flow,
-        )
-        t1 = perf_counter()
-        for j, i in enumerate(idxs):
-            onset_j = int(pooled_onset_b[j])
-            meta_j = (int(pooled_meta_b[j])
-                      if pooled_meta_b is not None else -1)
-            reports[i] = _finish_report(
-                preps[i], _Equilibrium(*(np.asarray(f)[j] for f in eq_b)),
-                _take_fluid(sh_tr_b, j), _take_fluid(tr_b, j),
-                (sh_onsets_b[j],
-                 sh_meta_b[j] if sh_meta_b is not None else None,
-                 onset_j if onset_j >= 0 else None,
-                 meta_j if meta_j >= 0 else None),
+        with span("report_solve", _prof):
+            kw = {k: v for k, v in p0.tr_kw.items()
+                  if k not in ("mode", "dt")}
+            # Stacked per-shard solve: [P, S, W].
+            sh_tr_b = fluid_two_tier_batched(
+                np.stack([p.lam_sw for p in group]),
+                np.stack([p.p12_sw for p in group]),
+                np.stack([np.broadcast_to(p.sh_mu1, full)
+                          for p in group]),
+                np.stack([np.broadcast_to(p.sh_mu2, full)
+                          for p in group]),
+                dt=p0.duration, **kw)
+            # Stacked pooled solve: [P, W].
+            tr_b = fluid_two_tier_batched(
+                np.stack([p.pool_lam for p in group]),
+                np.stack([p.pool_p12 for p in group]),
+                np.stack([np.broadcast_to(np.asarray(p.pool_mu1, float),
+                                          full[-1:]) for p in group]),
+                np.stack([np.broadcast_to(np.asarray(p.pool_mu2, float),
+                                          full[-1:]) for p in group]),
+                dt=p0.duration, **kw)
+            # Onset scans once over the whole stack (satellite of the
+            # batched pipeline: these used to re-run per report).
+            sh_onsets_b = np.asarray(sh_tr_b.onset())        # [P, S]
+            pooled_onset_b = np.asarray(tr_b.onset())        # [P]
+            sh_meta_b = (np.asarray(sh_tr_b.metastable_onset())
+                         if sh_tr_b.metastable is not None else None)
+            pooled_meta_b = (np.asarray(tr_b.metastable_onset())
+                             if tr_b.metastable is not None else None)
+            # Stationary solves for the whole group: [P, S] + [P].
+            eq_b = _solve_equilibrium(
+                np.stack([np.full(p.spec.n_shards, p.spec.lam, float)
+                          for p in group]),
+                np.stack([p.mu1_v for p in group]),
+                np.stack([p.mu2_v for p in group]),
+                np.stack([p.p12_sh for p in group]),
+                np.asarray([p.spec.lam for p in group], float),
+                np.asarray([p.rates.mu1 for p in group], float),
+                np.asarray([p.rates.mu2 for p in group], float),
+                np.asarray([p.p12 for p in group], float),
+                k=p0.spec.k_servers, flow=p0.spec.flow,
             )
-        t2 = perf_counter()
-        solve_s += t1 - t0
-        asm_s += t2 - t1
-    if _prof is not None:
-        _prof["report_solve"] = _prof.get("report_solve", 0.0) + solve_s
-        _prof["assembly"] = _prof.get("assembly", 0.0) + asm_s
+        with span("assembly", _prof):
+            for j, i in enumerate(idxs):
+                onset_j = int(pooled_onset_b[j])
+                meta_j = (int(pooled_meta_b[j])
+                          if pooled_meta_b is not None else -1)
+                reports[i] = _finish_report(
+                    preps[i],
+                    _Equilibrium(*(np.asarray(f)[j] for f in eq_b)),
+                    _take_fluid(sh_tr_b, j), _take_fluid(tr_b, j),
+                    (sh_onsets_b[j],
+                     sh_meta_b[j] if sh_meta_b is not None else None,
+                     onset_j if onset_j >= 0 else None,
+                     meta_j if meta_j >= 0 else None),
+                )
     return reports
 
 

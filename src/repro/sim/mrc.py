@@ -60,6 +60,7 @@ from repro.kernels.reuse_distance import (
     reuse_distances,
 )
 from repro.sim.engine import Tier1Counters, fault_owner, stream_for_spec
+from repro.sim.spans import span
 from repro.sim.spec import SimSpec
 from repro.storage.tiered_store import (
     partition_streams,
@@ -140,7 +141,8 @@ def _check_supported(spec: SimSpec) -> None:
 
 
 def mrc_tier1_counters(
-    spec: SimSpec, sizes: Sequence[int], trace=None
+    spec: SimSpec, sizes: Sequence[int], trace=None,
+    profile: Optional[dict] = None,
 ) -> dict[int, Tier1Counters]:
     """Exact per-shard :class:`~repro.sim.engine.Tier1Counters` for every
     cache size in ``sizes``, from one stream pass.
@@ -155,6 +157,13 @@ def mrc_tier1_counters(
     Raises ``ValueError`` for non-LRU policies, prefetch, or write traffic
     on a multi-window grid (see the module docstring for why those are
     outside the exactness domain).
+
+    ``profile`` (a mutable dict) collects the pass's stage spans
+    (:func:`repro.sim.spans.span`, seconds): ``mrc_prep`` (stream, owner
+    map, binning, partition, padding), ``mrc_prev_occurrence``,
+    ``mrc_reuse_distances`` (upload, the distance kernel and the distances
+    back on the host) and ``mrc_histogram`` (binning by size and the
+    counter assembly).
     """
     sizes_arr = np.unique(np.asarray(list(sizes), np.int64))
     if sizes_arr.size == 0:
@@ -163,117 +172,124 @@ def mrc_tier1_counters(
         raise ValueError("cache sizes must be >= 1")
     _check_supported(spec)
 
-    pages, is_write, times, n_pages, n_windows, window_dt = stream_for_spec(
-        spec, trace)
-    owner = fault_owner(spec, pages, times, n_pages)
-    has_writes = bool(np.asarray(is_write, bool).any())
-    if has_writes and n_windows > 1:
-        raise ValueError(
-            "MRC windowed counters require write-free traffic: a "
-            "write-back lands in the window of the evicting access, which "
-            "depends on the cache size — use a single window or the scan "
-            "engine"
-        )
+    with span("mrc_prep", profile):
+        pages, is_write, times, n_pages, n_windows, window_dt = (
+            stream_for_spec(spec, trace))
+        owner = fault_owner(spec, pages, times, n_pages)
+        has_writes = bool(np.asarray(is_write, bool).any())
+        if has_writes and n_windows > 1:
+            raise ValueError(
+                "MRC windowed counters require write-free traffic: a "
+                "write-back lands in the window of the evicting access, "
+                "which depends on the cache size — use a single window or "
+                "the scan engine"
+            )
 
-    S = spec.n_shards
-    if times is not None:
-        # Same float64 host-side binning as the scan-engine path: the raw
-        # (unsharded, full-precision) arrival times become int32 ids which
-        # then ride the shard scatter — bit-identical window assignment.
-        gwin = timestamp_window_ids(times, n_windows, window_dt)
-        sh_pages, sh_writes, counts, owner, sh_win = partition_streams(
-            pages, is_write, n_shards=S, mapping=spec.mapping,
-            n_pages=n_pages, n_windows=n_windows, window_ids=gwin,
-            owner=owner,
-        )
-    else:
-        sh_pages, sh_writes, counts, owner, sh_win = partition_streams(
-            pages, is_write, n_shards=S, mapping=spec.mapping,
-            n_pages=n_pages, n_windows=n_windows, owner=owner,
-        )
+        S = spec.n_shards
+        if times is not None:
+            # Same float64 host-side binning as the scan-engine path: the
+            # raw (unsharded, full-precision) arrival times become int32
+            # ids which then ride the shard scatter — bit-identical window
+            # assignment.
+            gwin = timestamp_window_ids(times, n_windows, window_dt)
+            sh_pages, sh_writes, counts, owner, sh_win = partition_streams(
+                pages, is_write, n_shards=S, mapping=spec.mapping,
+                n_pages=n_pages, n_windows=n_windows, window_ids=gwin,
+                owner=owner,
+            )
+        else:
+            sh_pages, sh_writes, counts, owner, sh_win = partition_streams(
+                pages, is_write, n_shards=S, mapping=spec.mapping,
+                n_pages=n_pages, n_windows=n_windows, owner=owner,
+            )
 
-    # --- one distance pass (padded to a power-of-two length bucket) -------
-    cap = sh_pages.shape[1]
-    capb = _bucket_cap(cap)
-    sh_pages_b = np.pad(sh_pages, ((0, 0), (0, capb - cap)))
-    prev, valid = prev_occurrence(sh_pages_b, counts)
-    dist = np.asarray(reuse_distances(prev, valid))        # int32 [S, capb]
-    win_b = np.full((S, capb), n_windows, np.int32)
-    win_b[:, :cap] = sh_win
+        # One distance pass, padded to a power-of-two length bucket.
+        cap = sh_pages.shape[1]
+        capb = _bucket_cap(cap)
+        sh_pages_b = np.pad(sh_pages, ((0, 0), (0, capb - cap)))
+    with span("mrc_prev_occurrence", profile):
+        prev, valid = prev_occurrence(sh_pages_b, counts)
+    with span("mrc_reuse_distances", profile):
+        dist = np.asarray(reuse_distances(prev, valid))    # int32 [S, capb]
+    with span("mrc_histogram", profile):
+        win_b = np.full((S, capb), n_windows, np.int32)
+        win_b[:, :cap] = sh_win
 
-    # --- histogram: (shard, window, size-bin) -> counts -------------------
-    m = int(sizes_arr.size)
-    vmask = valid
-    s_idx = np.broadcast_to(np.arange(S)[:, None], (S, capb))[vmask]
-    w_idx = win_b[vmask].astype(np.int64)
-    d_v = dist[vmask].astype(np.int64)
-    # bin = number of sizes <= d: request hits size index i iff bin <= i.
-    bins = np.searchsorted(sizes_arr, d_v, side="right")
-    composite = (s_idx * n_windows + w_idx) * (m + 1) + bins
-    hist = np.bincount(
-        composite, minlength=S * n_windows * (m + 1)
-    ).reshape(S, n_windows, m + 1)
-    win_req = hist.sum(axis=-1)                            # [S, W]
-    win_hits = np.cumsum(hist, axis=-1)[..., :m]           # [S, W, m]
-    win_miss = win_req[..., None] - win_hits
-    win_t2r = win_miss
-    # Free-line fills: the shard's first C misses (chronological — window
-    # ids are nondecreasing along each shard row) insert without evicting.
-    miss_before = np.cumsum(win_miss, axis=1) - win_miss
-    free = np.clip(sizes_arr[None, None, :] - miss_before, 0, win_miss)
-    win_ev = win_miss - free
+        # --- histogram: (shard, window, size-bin) -> counts ---------------
+        m = int(sizes_arr.size)
+        vmask = valid
+        s_idx = np.broadcast_to(np.arange(S)[:, None], (S, capb))[vmask]
+        w_idx = win_b[vmask].astype(np.int64)
+        d_v = dist[vmask].astype(np.int64)
+        # bin = number of sizes <= d: a request hits size index i iff
+        # bin <= i.
+        bins = np.searchsorted(sizes_arr, d_v, side="right")
+        composite = (s_idx * n_windows + w_idx) * (m + 1) + bins
+        hist = np.bincount(
+            composite, minlength=S * n_windows * (m + 1)
+        ).reshape(S, n_windows, m + 1)
+        win_req = hist.sum(axis=-1)                        # [S, W]
+        win_hits = np.cumsum(hist, axis=-1)[..., :m]       # [S, W, m]
+        win_miss = win_req[..., None] - win_hits
+        win_t2r = win_miss
+        # Free-line fills: the shard's first C misses (chronological —
+        # window ids are nondecreasing along each shard row) insert without
+        # evicting.
+        miss_before = np.cumsum(win_miss, axis=1) - win_miss
+        free = np.clip(sizes_arr[None, None, :] - miss_before, 0, win_miss)
+        win_ev = win_miss - free
 
-    win_t2w = np.zeros_like(win_miss)
-    if has_writes:
-        win_t2w[:, 0, :] = _tier2_writes(
-            sizes_arr, s_idx, vmask, sh_pages_b, d_v,
-            sh_writes, counts, S,
-        )
+        win_t2w = np.zeros_like(win_miss)
+        if has_writes:
+            win_t2w[:, 0, :] = _tier2_writes(
+                sizes_arr, s_idx, vmask, sh_pages_b, d_v,
+                sh_writes, counts, S,
+            )
 
-    # --- assemble Tier1Counters per size ----------------------------------
-    counts64 = np.asarray(counts, np.int64)
-    writes64 = np.bincount(owner[np.asarray(is_write, bool)],
-                           minlength=S).astype(np.int64)
-    zeros_w = np.zeros((S, n_windows), np.int64)
-    win_eu = np.zeros((S, n_windows, ol.N_EXPERTS, m), np.int64)
-    win_eu[:, :, _LRU_EXPERT, :] = win_ev
-    # Fixed-policy weights never move: each window with a real request
-    # snapshots the uniform initial vector, empty windows stay zero
-    # (exactly the engine's accumulator semantics — including the f32
-    # representation of 1/E the engine's accumulator carries).
-    uniform = (np.ones(ol.N_EXPERTS, np.float32)
-               / ol.N_EXPERTS).astype(float)
-    win_wt = np.where(
-        (win_req > 0)[..., None], uniform, 0.0
-    )                                                      # [S, W, E]
+        # --- assemble Tier1Counters per size ------------------------------
+        counts64 = np.asarray(counts, np.int64)
+        writes64 = np.bincount(owner[np.asarray(is_write, bool)],
+                               minlength=S).astype(np.int64)
+        zeros_w = np.zeros((S, n_windows), np.int64)
+        win_eu = np.zeros((S, n_windows, ol.N_EXPERTS, m), np.int64)
+        win_eu[:, :, _LRU_EXPERT, :] = win_ev
+        # Fixed-policy weights never move: each window with a real request
+        # snapshots the uniform initial vector, empty windows stay zero
+        # (exactly the engine's accumulator semantics — including the f32
+        # representation of 1/E the engine's accumulator carries).
+        uniform = (np.ones(ol.N_EXPERTS, np.float32)
+                   / ol.N_EXPERTS).astype(float)
+        win_wt = np.where(
+            (win_req > 0)[..., None], uniform, 0.0
+        )                                                  # [S, W, E]
 
-    out: dict[int, Tier1Counters] = {}
-    for i, size in enumerate(sizes_arr):
-        hits_i = win_hits[..., i].astype(np.int64)
-        miss_i = win_miss[..., i].astype(np.int64)
-        ev_i = win_ev[..., i].astype(np.int64)
-        t2w_i = win_t2w[..., i].astype(np.int64)
-        out[int(size)] = Tier1Counters(
-            requests=counts64,
-            reads=counts64 - writes64,
-            writes=writes64,
-            hits=hits_i.sum(axis=1),
-            misses=miss_i.sum(axis=1),
-            prefetch_hits=np.zeros(S, np.int64),
-            tier2_reads=miss_i.sum(axis=1),
-            tier2_writes=t2w_i.sum(axis=1),
-            evictions=ev_i.sum(axis=1),
-            win_requests=win_req.astype(np.int64),
-            win_hits=hits_i,
-            win_misses=miss_i,
-            win_prefetch_hits=zeros_w,
-            win_tier2_reads=miss_i,
-            win_tier2_writes=t2w_i,
-            win_evictions=ev_i,
-            win_expert_use=win_eu[..., i],
-            win_weights=win_wt,
-        )
-    return out
+        out: dict[int, Tier1Counters] = {}
+        for i, size in enumerate(sizes_arr):
+            hits_i = win_hits[..., i].astype(np.int64)
+            miss_i = win_miss[..., i].astype(np.int64)
+            ev_i = win_ev[..., i].astype(np.int64)
+            t2w_i = win_t2w[..., i].astype(np.int64)
+            out[int(size)] = Tier1Counters(
+                requests=counts64,
+                reads=counts64 - writes64,
+                writes=writes64,
+                hits=hits_i.sum(axis=1),
+                misses=miss_i.sum(axis=1),
+                prefetch_hits=np.zeros(S, np.int64),
+                tier2_reads=miss_i.sum(axis=1),
+                tier2_writes=t2w_i.sum(axis=1),
+                evictions=ev_i.sum(axis=1),
+                win_requests=win_req.astype(np.int64),
+                win_hits=hits_i,
+                win_misses=miss_i,
+                win_prefetch_hits=zeros_w,
+                win_tier2_reads=miss_i,
+                win_tier2_writes=t2w_i,
+                win_evictions=ev_i,
+                win_expert_use=win_eu[..., i],
+                win_weights=win_wt,
+            )
+        return out
 
 
 def _tier2_writes(

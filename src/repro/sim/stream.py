@@ -42,8 +42,8 @@ and the host collapses the composite axis back into per-window totals
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from time import perf_counter
 from typing import Optional
 
 import numpy as np
@@ -62,6 +62,7 @@ from repro.sim.engine import (
     report_from_counters,
     stream_for_spec,
 )
+from repro.sim.spans import count, span
 from repro.sim.spec import SimSpec
 from repro.storage.tiered_store import (
     init_stream_carry,
@@ -187,12 +188,17 @@ def stream_tier1_counters(
     against. ``engine`` selects the fused cache-scan request loop
     (default) or the original ``"scan"`` reference (bit-exact either way).
 
-    ``profile`` (a mutable dict) accumulates per-chunk wall-clock
-    sub-timings: ``stream_chunk_host`` (generation + binning +
-    partitioning), ``stream_chunk_dispatch`` (device_put + async engine
+    ``profile`` (a mutable dict) accumulates the replay's stage spans
+    (:func:`repro.sim.spans.span`, seconds): ``stream_resume_prep`` (the
+    whole-trace stream, window binning and owner map of a given trace),
+    ``stream_chunk_host`` (generation + binning + partitioning of a
+    chunk), ``stream_chunk_dispatch`` (device_put + async engine
     submission), ``stream_chunk_wait`` (blocking materialization of the
     final carry; per-chunk blocking too when ``donate=False``) and
-    ``stream_chunks`` (chunk count)."""
+    ``stream_engine`` (from the first chunk's submission to the carry on
+    the host), and the counters ``stream_chunks`` (chunks),
+    ``stream_scan_steps`` (engine steps: ``cap * n_shards`` a chunk, pads
+    included) and ``stream_requests`` (the chunks' real requests)."""
     if chunk < 1:
         raise ValueError("chunk must be >= 1")
     prof = profile
@@ -209,17 +215,18 @@ def stream_tier1_counters(
     else:
         gen = None
         n_tenants = 0
-        pages, is_write, times, n_pages, n_windows, window_dt = (
-            stream_for_spec(spec, trace))
-        total = int(pages.shape[0])
-        # Whole-stream host precompute, identical to the one-shot path:
-        # window binning (float64) and the fault-schedule owner remap are
-        # global maps, so chunking cannot perturb them.
-        if window_dt is not None:
-            gwin_all = timestamp_window_ids(times, n_windows, window_dt)
-        else:
-            gwin_all = stream_window_ids(total, n_windows)
-        owner_all = fault_owner(spec, pages, times, n_pages)
+        with span("stream_resume_prep", prof):
+            pages, is_write, times, n_pages, n_windows, window_dt = (
+                stream_for_spec(spec, trace))
+            total = int(pages.shape[0])
+            # Whole-stream host precompute, identical to the one-shot
+            # path: window binning (float64) and the fault-schedule owner
+            # remap are global maps, so chunking cannot perturb them.
+            if window_dt is not None:
+                gwin_all = timestamp_window_ids(times, n_windows, window_dt)
+            else:
+                gwin_all = stream_window_ids(total, n_windows)
+            owner_all = fault_owner(spec, pages, times, n_pages)
     # Composite window ids interleave the tenant axis into the engine's
     # windowed scatter: id = window * n_tenants + tenant. The engine runs
     # at W * n_tenants windows; the host collapses the axis afterwards.
@@ -245,6 +252,7 @@ def stream_tier1_counters(
         last_tenant = (np.full((n_shards, n_windows), -1, np.int32)
                        if tenant else None)
 
+    start = offset
     stop = total if max_requests is None else min(total,
                                                   offset + int(max_requests))
     primary, fallback = _chunk_caps(chunk, n_shards)
@@ -253,64 +261,63 @@ def stream_tier1_counters(
                               engine=engine)
     hyper = spec.store.hyper()
 
-    while offset < stop:
-        tc0 = perf_counter()
-        m = min(chunk, stop - offset)
-        if tenant:
-            p, w, t, tids = gen.take(m)
-            own = fault_owner(spec, p, t, n_pages)
-            if window_dt is not None:
-                win = timestamp_window_ids(t, n_windows, window_dt)
-            else:
-                g = offset + np.arange(m, dtype=np.int64)
-                win = ((g * n_windows) // total).astype(np.int32)
-            # Last tenant per (shard, window): duplicate fancy-index
-            # assignment keeps the final occurrence — exactly "the tenant
-            # of this shard's last request in this window so far".
-            last_tenant[own, win] = tids
-            cwin = win * n_tenants + tids
-        else:
-            sl = slice(offset, offset + m)
-            p, w = pages[sl], is_write[sl]
-            own, cwin = owner_all[sl], gwin_all[sl]
-        cnt = np.bincount(own, minlength=n_shards)
-        cap = primary if int(cnt.max()) <= primary else fallback
-        sh_p, sh_w, cnt, _, sh_win = partition_streams(
-            p, w, n_shards=n_shards, mapping=spec.mapping, n_pages=n_pages,
-            cap=cap, n_windows=eng_windows, window_ids=cwin, owner=own)
-        counts += cnt
-        shard_writes += np.bincount(own[w], minlength=n_shards)
-        tc1 = perf_counter()
-        # Async pipeline: device_put + dispatch return before the chunk
-        # finishes computing, so the next iteration's host work (generate,
-        # bin, partition) overlaps device compute. donate=False is the
-        # deliberately-synchronous naive baseline.
-        dev = jax.device_put((sh_p, sh_w, sh_win))
-        carry = eng(hyper, carry, *dev)
-        # The resumable chunk mode has one implementation, the XLA engine.
-        record_paths("cache_scan", np.full(n_shards, XLA))
-        tc2 = perf_counter()
-        if not donate:
-            jax.block_until_ready(carry)
-        offset += m
-        if prof is not None:
-            prof["stream_chunk_host"] = (
-                prof.get("stream_chunk_host", 0.0) + (tc1 - tc0))
-            prof["stream_chunk_dispatch"] = (
-                prof.get("stream_chunk_dispatch", 0.0) + (tc2 - tc1))
-            prof["stream_chunk_wait"] = (
-                prof.get("stream_chunk_wait", 0.0)
-                + (perf_counter() - tc2))
-            prof["stream_chunks"] = prof.get("stream_chunks", 0) + 1
+    # stream_engine opens at the first chunk's submission and closes with
+    # the carry on the host: the span the chunk pipeline runs under.
+    with contextlib.ExitStack() as engine_span:
+        while offset < stop:
+            m = min(chunk, stop - offset)
+            with span("stream_chunk_host", prof):
+                if tenant:
+                    p, w, t, tids = gen.take(m)
+                    own = fault_owner(spec, p, t, n_pages)
+                    if window_dt is not None:
+                        win = timestamp_window_ids(t, n_windows, window_dt)
+                    else:
+                        g = offset + np.arange(m, dtype=np.int64)
+                        win = ((g * n_windows) // total).astype(np.int32)
+                    # Last tenant per (shard, window): duplicate fancy-
+                    # index assignment keeps the final occurrence —
+                    # exactly "the tenant of this shard's last request in
+                    # this window so far".
+                    last_tenant[own, win] = tids
+                    cwin = win * n_tenants + tids
+                else:
+                    sl = slice(offset, offset + m)
+                    p, w = pages[sl], is_write[sl]
+                    own, cwin = owner_all[sl], gwin_all[sl]
+                cnt = np.bincount(own, minlength=n_shards)
+                cap = primary if int(cnt.max()) <= primary else fallback
+                sh_p, sh_w, cnt, _, sh_win = partition_streams(
+                    p, w, n_shards=n_shards, mapping=spec.mapping,
+                    n_pages=n_pages, cap=cap, n_windows=eng_windows,
+                    window_ids=cwin, owner=own)
+                counts += cnt
+                shard_writes += np.bincount(own[w], minlength=n_shards)
+            if offset == start:
+                engine_span.enter_context(span("stream_engine", prof))
+            # Async pipeline: device_put + dispatch return before the chunk
+            # finishes computing, so the next iteration's host work
+            # (generate, bin, partition) overlaps device compute.
+            # donate=False is the deliberately-synchronous naive baseline.
+            with span("stream_chunk_dispatch", prof):
+                dev = jax.device_put((sh_p, sh_w, sh_win))
+                carry = eng(hyper, carry, *dev)
+                # The resumable chunk mode has one implementation, the XLA
+                # engine.
+                record_paths("cache_scan", np.full(n_shards, XLA))
+            with span("stream_chunk_wait", prof):
+                if not donate:
+                    jax.block_until_ready(carry)
+            offset += m
+            count("stream_chunks", prof)
+            count("stream_scan_steps", prof, cap * n_shards)
+            count("stream_requests", prof, m)
 
-    # Materialize the carry on the host once: the numpy copies survive the
-    # next resume's donation, feed the counter assembly below, and make
-    # the checkpoint picklable.
-    tw0 = perf_counter()
-    carry_host = jax.tree.map(np.asarray, carry)
-    if prof is not None:
-        prof["stream_chunk_wait"] = (
-            prof.get("stream_chunk_wait", 0.0) + (perf_counter() - tw0))
+        # Materialize the carry on the host once: the numpy copies survive
+        # the next resume's donation, feed the counter assembly below, and
+        # make the checkpoint picklable.
+        with span("stream_chunk_wait", prof):
+            carry_host = jax.tree.map(np.asarray, carry)
     stats = stream_stats_from_carry(carry_host, counts)
 
     tenant_ctr = None

@@ -72,7 +72,6 @@ import itertools
 import json
 import logging
 import warnings
-from time import perf_counter
 from typing import Callable, Mapping, NamedTuple, Optional, Sequence
 
 import numpy as np
@@ -97,6 +96,7 @@ from repro.sim.engine import (
     tier1_counters,
 )
 from repro.sim.mrc import mrc_tier1_counters, mrc_unsupported_reason
+from repro.sim.spans import span
 from repro.sim.stream import stream_tier1_counters
 from repro.sim.spec import SimSpec
 from repro.storage.tiered_store import (
@@ -161,11 +161,15 @@ class SweepResult:
     axes: dict
     points: tuple          # override dict per point
     reports: tuple         # SimReport per point
-    # sweep(profile=True): per-stage wall-clock seconds — stream_gen
-    # (host-side traffic generation + partitioning for the megabatch),
-    # engine_dispatch (device engine calls + gather, plus the routed
-    # stream/MRC/unbatched paths), report_solve (queuing-network solves),
-    # assembly (SimReport construction) and total.
+    # sweep(profile=True): per-stage wall-clock seconds, one key per
+    # repro.sim.spans span — stream_gen (host-side traffic generation +
+    # partitioning for the megabatch; traffic_gen is its generation
+    # alone), engine_dispatch_submit / engine_dispatch_wait (the
+    # megabatch's device engine calls and their gather), route_stream /
+    # route_mrc (the routed chunked-replay and MRC paths, with their own
+    # stream_chunk_* / mrc_* spans), unbatched (batch=False's per-point
+    # engine runs), report_solve (queuing-network solves), assembly
+    # (SimReport construction) and total.
     profile: Optional[dict] = None
 
     def rows(self) -> list[dict]:
@@ -225,14 +229,16 @@ def _mrc_group_key(spec: SimSpec) -> tuple:
 
 
 def _route_mrc(
-    unique: Mapping[tuple, SimSpec], mrc: str
+    unique: Mapping[tuple, SimSpec], mrc: str,
+    profile: Optional[dict] = None,
 ) -> dict[tuple, Tier1Counters]:
     """Serve every eligible size-only signature group via the one-pass MRC
     engine. Returns ``{signature: counters}`` for the routed signatures
     (bit-identical to the scan engine); the caller runs the rest through
     the batched engine. ``mrc="require"`` raises if any group is
     ineligible; ``"auto"`` routes only groups with >= 2 sizes (a single
-    size gains nothing over the engine)."""
+    size gains nothing over the engine). ``profile`` collects the MRC
+    pass's ``mrc_*`` spans (:func:`repro.sim.mrc.mrc_tier1_counters`)."""
     groups: dict[tuple, list[tuple]] = {}
     for sig, spec in unique.items():
         groups.setdefault(_mrc_group_key(spec), []).append(sig)
@@ -261,7 +267,7 @@ def _route_mrc(
             "(policy=lru, n_shards=%d)",
             len(sizes), rep.n_shards,
         )
-        by_size = mrc_tier1_counters(rep, sizes)
+        by_size = mrc_tier1_counters(rep, sizes, profile=profile)
         for s in sigs:
             counters[s] = by_size[int(unique[s].store.n_lines)]
     return counters
@@ -337,7 +343,8 @@ def _batched_engine(
     if fn is not None:
         return fn
 
-    def body(hyper, sh_pages, sh_writes, sh_win):
+    # Named for the profiler: the trace shows jit_megabatch_engine.
+    def megabatch_engine(hyper, sh_pages, sh_writes, sh_win):
         _ENGINE_COMPILES[0] += 1  # trace-time: once per XLA compile
 
         def point(h, p, w, wi):
@@ -354,14 +361,15 @@ def _batched_engine(
     if len(devices) > 1:
         spec = PartitionSpec("points")
         jfn = jax.jit(shard_map(
-            body,
+            megabatch_engine,
             mesh=device_mesh("points", devices),
             in_specs=(spec,) * n_in,
             out_specs=spec,
             check_vma=True,
         ), donate_argnums=(1, 2, 3) if donate else ())
     else:
-        jfn = jax.jit(body, donate_argnums=(1, 2, 3) if donate else ())
+        jfn = jax.jit(megabatch_engine,
+                      donate_argnums=(1, 2, 3) if donate else ())
 
     if donate:
         # The stacked stream operands have no same-shape output to alias
@@ -417,6 +425,49 @@ class _PendingBucket:
         return out
 
 
+def _member(spec: SimSpec, sig: tuple, n_shards: int, n_windows: int,
+            timed: bool, prof: Optional[dict]) -> _Member:
+    """One signature's stream made, failed over, binned and partitioned for
+    stacking; its traffic generation alone is the ``traffic_gen`` span."""
+    n_windows_i, window_dt = spec.window_grid()
+    assert n_windows_i == n_windows  # grouped by batch key
+    if timed:
+        with span("traffic_gen", prof):
+            pages, is_write, times = make_timed_stream(
+                spec.traffic, default_rate=spec.agg_rate())
+        n_pages_i = sim_n_pages(spec, pages)
+        # Fault schedules ride the megabatch as *data*: the failover
+        # remap happens host-side and only reshuffles the owner
+        # operand, so a fault grid shares one compiled engine.
+        own = fault_owner(spec, pages, times, n_pages_i)
+        # Bin arrival times host-side (float64) into the same int32
+        # window-id operand the index path uses — one engine, exact
+        # long-horizon binning.
+        gwin = timestamp_window_ids(times, n_windows, window_dt)
+        sh_p, sh_w, counts, owner, sh_tw = partition_streams(
+            pages, is_write, n_shards=n_shards, mapping=spec.mapping,
+            n_pages=n_pages_i, n_windows=n_windows, window_ids=gwin,
+            owner=own,
+        )
+    else:
+        with span("traffic_gen", prof):
+            pages, is_write = make_stream(spec.traffic)
+        sh_p, sh_w, counts, owner, sh_tw = partition_streams(
+            pages, is_write, n_shards=n_shards, mapping=spec.mapping,
+            n_pages=sim_n_pages(spec, pages), n_windows=n_windows,
+        )
+    return _Member(
+        bucket=_bucket_cap(sh_p.shape[1]),
+        sig=sig,
+        spec=spec,
+        sh_pages=sh_p,
+        sh_writes=sh_w,
+        sh_win=sh_tw,
+        counts=counts,
+        shard_writes=np.bincount(owner[is_write], minlength=n_shards),
+    )
+
+
 def _dispatch_group(
     specs: list[SimSpec], sigs: list, *, unroll: int, devices: tuple,
     engine: str = "fused", donate: bool = True,
@@ -425,116 +476,77 @@ def _dispatch_group(
     """Partition, bucket, pad and asynchronously dispatch every unique cache
     signature of one batch-key group. Returns pending buckets; device compute
     proceeds while the caller prepares and dispatches later groups.
-    ``_prof`` accumulates ``stream_gen`` / ``engine_dispatch`` seconds
-    (submission side — see ``engine_dispatch_submit``)."""
+    ``_prof`` collects the ``stream_gen`` span (with ``traffic_gen`` per
+    member inside it) and the ``engine_dispatch_submit`` span."""
     store_static = specs[0].store.static_config()
     n_shards = specs[0].n_shards
     n_windows, window_dt0 = specs[0].window_grid()
     timed = window_dt0 is not None
     n_dev = len(devices)
 
-    t0 = perf_counter()
-    members = []
-    for spec, sig in zip(specs, sigs):
-        n_windows_i, window_dt = spec.window_grid()
-        assert n_windows_i == n_windows  # grouped by batch key
-        if timed:
-            pages, is_write, times = make_timed_stream(
-                spec.traffic, default_rate=spec.agg_rate())
-            n_pages_i = sim_n_pages(spec, pages)
-            # Fault schedules ride the megabatch as *data*: the failover
-            # remap happens host-side and only reshuffles the owner
-            # operand, so a fault grid shares one compiled engine.
-            own = fault_owner(spec, pages, times, n_pages_i)
-            # Bin arrival times host-side (float64) into the same int32
-            # window-id operand the index path uses — one engine, exact
-            # long-horizon binning.
-            gwin = timestamp_window_ids(times, n_windows, window_dt)
-            sh_p, sh_w, counts, owner, sh_tw = partition_streams(
-                pages, is_write, n_shards=n_shards, mapping=spec.mapping,
-                n_pages=n_pages_i, n_windows=n_windows, window_ids=gwin,
-                owner=own,
+    with span("stream_gen", _prof):
+        members = [_member(spec, sig, n_shards, n_windows, timed, _prof)
+                   for spec, sig in zip(specs, sigs)]
+
+    # Submission side of the engine stage: host→device transfer of the
+    # stacked operands and the async calls (device compute is still in
+    # flight when this returns). The wait side (device compute + gather
+    # transfer) is the caller's ``engine_dispatch_wait`` span.
+    with span("engine_dispatch_submit", _prof):
+        buckets: dict[int, list[_Member]] = {}
+        for m in members:
+            buckets.setdefault(m.bucket, []).append(m)
+
+        pending = []
+        for cap, group in sorted(buckets.items()):
+            n = len(group)
+            # The point axis must split over the devices.
+            n_pad = -(-n // n_dev) * n_dev
+            sh_pages = np.zeros((n_pad, n_shards, cap), np.int32)
+            sh_writes = np.zeros((n_pad, n_shards, cap), bool)
+            # Bucket-extension positions are padding: window id n_windows
+            # drops them from the windowed counters (so windowed telemetry
+            # is bit-identical across bucket choices).
+            sh_win = np.full((n_pad, n_shards, cap), n_windows, np.int32)
+            for i, m in enumerate(group):
+                w = m.sh_pages.shape[1]
+                # Rows come pre-padded with their shard's last page;
+                # extending that edge-repeat keeps the padding a pure-hit
+                # stream.
+                sh_pages[i, :, :w] = m.sh_pages
+                sh_pages[i, :, w:] = m.sh_pages[:, -1:]
+                sh_writes[i, :, :w] = m.sh_writes
+                sh_win[i, :, :w] = m.sh_win
+            # Padded points: discarded after the gather.
+            sh_pages[n:] = sh_pages[0]
+            sh_writes[n:] = sh_writes[0]
+
+            stores = [m.spec.store for m in group]
+            stores += [stores[0]] * (n_pad - n)
+            hyper = _stack_hypers(stores)
+
+            eng = _batched_engine(store_static, unroll, devices, n_windows,
+                                  engine, donate)
+            log.info(
+                "sweep: dispatch %d points x %d shards @ len %d "
+                "(n_lines=%d, windows=%d, timed=%s, devices=%d)",
+                n, n_shards, cap, store_static.n_lines, n_windows, timed,
+                n_dev,
             )
-        else:
-            pages, is_write = make_stream(spec.traffic)
-            sh_p, sh_w, counts, owner, sh_tw = partition_streams(
-                pages, is_write, n_shards=n_shards, mapping=spec.mapping,
-                n_pages=sim_n_pages(spec, pages), n_windows=n_windows,
-            )
-        members.append(_Member(
-            bucket=_bucket_cap(sh_p.shape[1]),
-            sig=sig,
-            spec=spec,
-            sh_pages=sh_p,
-            sh_writes=sh_w,
-            sh_win=sh_tw,
-            counts=counts,
-            shard_writes=np.bincount(owner[is_write], minlength=n_shards),
-        ))
-
-    t1 = perf_counter()
-    if _prof is not None:
-        _prof["stream_gen"] = _prof.get("stream_gen", 0.0) + (t1 - t0)
-
-    buckets: dict[int, list[_Member]] = {}
-    for m in members:
-        buckets.setdefault(m.bucket, []).append(m)
-
-    pending = []
-    for cap, group in sorted(buckets.items()):
-        n = len(group)
-        n_pad = -(-n // n_dev) * n_dev  # point axis must split over devices
-        sh_pages = np.zeros((n_pad, n_shards, cap), np.int32)
-        sh_writes = np.zeros((n_pad, n_shards, cap), bool)
-        # Bucket-extension positions are padding: window id n_windows
-        # drops them from the windowed counters (so windowed telemetry is
-        # bit-identical across bucket choices).
-        sh_win = np.full((n_pad, n_shards, cap), n_windows, np.int32)
-        for i, m in enumerate(group):
-            w = m.sh_pages.shape[1]
-            # Rows come pre-padded with their shard's last page; extending
-            # that edge-repeat keeps the padding a pure-hit stream.
-            sh_pages[i, :, :w] = m.sh_pages
-            sh_pages[i, :, w:] = m.sh_pages[:, -1:]
-            sh_writes[i, :, :w] = m.sh_writes
-            sh_win[i, :, :w] = m.sh_win
-        sh_pages[n:] = sh_pages[0]  # padded points: discarded after gather
-        sh_writes[n:] = sh_writes[0]
-
-        stores = [m.spec.store for m in group]
-        stores += [stores[0]] * (n_pad - n)
-        hyper = _stack_hypers(stores)
-
-        eng = _batched_engine(store_static, unroll, devices, n_windows,
-                              engine, donate)
-        log.info(
-            "sweep: dispatch %d points x %d shards @ len %d "
-            "(n_lines=%d, windows=%d, timed=%s, devices=%d)",
-            n, n_shards, cap, store_static.n_lines, n_windows, timed, n_dev,
-        )
-        operands = (sh_pages, sh_writes, sh_win)
-        if n_dev == 1:
-            # One device: place the operands there; the engine follows.
-            operands = jax.device_put(operands, devices[0])
-            hyper = jax.device_put(hyper, devices[0])
-        stats = eng(hyper, *operands)
-        pending.append(_PendingBucket(
-            sigs=[m.sig for m in group],
-            counts=[m.counts for m in group],
-            writes=[m.shard_writes for m in group],
-            cap=cap,
-            stats=stats,
-        ))
-    if _prof is not None:
-        # Submission side of the engine stage: tracing + host→device
-        # transfer of the stacked operands (the calls are async — device
-        # compute is still in flight when this returns). The wait side
-        # (device compute + gather transfer) lands on
-        # ``engine_dispatch_wait``; ``engine_dispatch`` stays their sum.
-        dt = perf_counter() - t1
-        _prof["engine_dispatch"] = _prof.get("engine_dispatch", 0.0) + dt
-        _prof["engine_dispatch_submit"] = (
-            _prof.get("engine_dispatch_submit", 0.0) + dt)
+            operands = (sh_pages, sh_writes, sh_win)
+            if n_dev == 1:
+                # One device: place the operands there; the engine
+                # follows.
+                operands = jax.device_put(operands, devices[0])
+                hyper = jax.device_put(hyper, devices[0])
+            stats = eng(hyper, *operands)
+            pending.append(_PendingBucket(
+                sigs=[m.sig for m in group],
+                counts=[m.counts for m in group],
+                writes=[m.shard_writes for m in group],
+                cap=cap,
+                stats=stats,
+            ))
     return pending
 
 
@@ -600,16 +612,17 @@ def sweep(
     there. The routed stream/MRC paths and the report stage run on the
     default device.
 
-    ``profile=True`` attaches a per-stage wall-clock breakdown (stream
-    gen / engine dispatch / report solve / assembly, seconds) to
-    :attr:`SweepResult.profile`, serialized by ``to_json``. The engine
-    stage is split into ``engine_dispatch_submit`` (host-side tracing +
-    transfer of async dispatches) and ``engine_dispatch_wait``
-    (device compute + gather back to host); ``engine_dispatch`` is their
-    sum, with the routed stream/MRC/unbatched paths' cost included
-    (chunked streaming additionally reports per-chunk
-    ``stream_chunk_host`` / ``stream_chunk_dispatch`` /
-    ``stream_chunk_wait`` timings).
+    ``profile=True`` attaches a per-stage wall-clock breakdown (seconds,
+    one key per :func:`repro.sim.spans.span`, each also a ``repro.<key>``
+    event in a profiler trace) to :attr:`SweepResult.profile`, serialized
+    by ``to_json``: ``stream_gen`` (with ``traffic_gen``, the generation
+    alone, inside it), ``engine_dispatch_submit`` (host-side transfer and
+    submission of the async megabatch calls), ``engine_dispatch_wait``
+    (device compute + gather back to host), ``route_stream`` and
+    ``route_mrc`` (the routed chunked-replay and MRC paths, which add
+    their own ``stream_chunk_*`` and ``mrc_*`` spans), ``unbatched``
+    (``batch=False``'s per-signature engine runs), ``report_solve``,
+    ``assembly`` and ``total``.
     """
     if mrc not in ("auto", "off", "require"):
         raise ValueError(
@@ -639,14 +652,33 @@ def sweep(
     specs = [base.replace(**pt) for pt in points]
     devices = tuple(jax.local_devices() if devices is None else devices)
     solver = ("batched" if batch else "scalar") if report == "auto" else report
-    prof: Optional[dict] = (
-        {"stream_gen": 0.0, "engine_dispatch": 0.0,
-         "engine_dispatch_submit": 0.0, "engine_dispatch_wait": 0.0,
-         "report_solve": 0.0, "assembly": 0.0}
-        if profile else None
+    prof: Optional[dict] = None
+    if profile:
+        stages = (("stream_gen", "traffic_gen", "route_stream", "route_mrc")
+                  if batch else ("unbatched",))
+        prof = dict.fromkeys(
+            stages + ("engine_dispatch_submit", "engine_dispatch_wait",
+                      "report_solve", "assembly"), 0.0)
+    with span("total", prof):
+        reports = _sweep(specs, batch=batch, unroll=unroll, mrc=mrc,
+                         stream=stream, solver=solver, engine=engine,
+                         donate=donate, devices=devices, prof=prof)
+    if prof is not None:
+        prof["n_points"] = len(points)
+        prof["report_solver"] = solver
+    return SweepResult(
+        base=base,
+        axes=axes_dict,
+        points=tuple(points),
+        reports=tuple(reports),
+        profile=prof,
     )
-    t_start = perf_counter()
 
+
+def _sweep(specs: list[SimSpec], *, batch: bool, unroll: int, mrc: str,
+           stream: str, solver: str, engine: str, donate: bool,
+           devices: tuple, prof: Optional[dict]) -> list[SimReport]:
+    """:func:`sweep`'s work once its grid is expanded: a report per spec."""
     # One cache run per unique signature.
     sig_of = [spec.cache_signature() for spec in specs]
     unique: dict[tuple, SimSpec] = {}
@@ -655,18 +687,16 @@ def sweep(
 
     counters: dict[tuple, Tier1Counters] = {}
     tenant_ctrs: dict[tuple, TenantCounters] = {}
-    t0 = perf_counter()
     if batch:
-        counters, tenant_ctrs = _route_stream(unique, stream,
-                                              engine=engine, profile=prof)
-    if batch and mrc != "off":
-        counters.update(_route_mrc(
-            {s: sp for s, sp in unique.items() if s not in counters}, mrc))
-    if prof is not None:
-        # The routed paths generate their streams internally; their whole
-        # cost lands on engine_dispatch.
-        prof["engine_dispatch"] += perf_counter() - t0
-    if batch:
+        # The routed paths generate their streams internally.
+        with span("route_stream", prof):
+            counters, tenant_ctrs = _route_stream(unique, stream,
+                                                  engine=engine, profile=prof)
+        if mrc != "off":
+            with span("route_mrc", prof):
+                counters.update(_route_mrc(
+                    {s: sp for s, sp in unique.items() if s not in counters},
+                    mrc, profile=prof))
         groups: dict[tuple, list[tuple]] = {}
         for sig, spec in unique.items():
             if sig in counters:  # already served by the MRC path
@@ -687,36 +717,19 @@ def sweep(
                                 unroll=unroll, devices=devices,
                                 engine=engine, donate=donate, _prof=prof)
             )
-        t0 = perf_counter()
-        for bucket in pending:
-            counters.update(bucket.gather())
-        if prof is not None:
-            # Gather blocks on device compute: this is the wait side of
-            # the engine stage (device compute + device→host transfer).
-            dt = perf_counter() - t0
-            prof["engine_dispatch"] += dt
-            prof["engine_dispatch_wait"] += dt
+        # Gather blocks on device compute: the wait side of the engine
+        # stage (device compute + device→host transfer).
+        with span("engine_dispatch_wait", prof):
+            for bucket in pending:
+                counters.update(bucket.gather())
     else:
-        t0 = perf_counter()
-        for sig, spec in unique.items():
-            log.info("sweep: run %s", sig)
-            counters[sig] = tier1_counters(spec, engine=engine)
-        if prof is not None:
-            prof["engine_dispatch"] += perf_counter() - t0
+        with span("unbatched", prof):
+            for sig, spec in unique.items():
+                log.info("sweep: run %s", sig)
+                counters[sig] = tier1_counters(spec, engine=engine)
 
-    reports = batched_reports(
+    return batched_reports(
         [(spec, counters[sig], tenant_ctrs.get(sig))
          for spec, sig in zip(specs, sig_of)],
         solver=solver, _prof=prof,
-    )
-    if prof is not None:
-        prof["total"] = perf_counter() - t_start
-        prof["n_points"] = len(points)
-        prof["report_solver"] = solver
-    return SweepResult(
-        base=base,
-        axes=axes_dict,
-        points=tuple(points),
-        reports=tuple(reports),
-        profile=prof,
     )

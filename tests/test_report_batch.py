@@ -165,11 +165,13 @@ def test_sweep_report_modes_and_profile():
         _assert_reports_close(a, b)
     assert rs.profile is None
     prof = rb.profile
-    assert set(prof) >= {"stream_gen", "engine_dispatch", "report_solve",
-                         "assembly", "total", "n_points"}
+    stages = ("stream_gen", "traffic_gen", "route_stream", "route_mrc",
+              "engine_dispatch_submit", "engine_dispatch_wait",
+              "report_solve", "assembly")
+    assert set(prof) >= set(stages) | {"total", "n_points"}
+    assert "engine_dispatch" not in prof
     assert prof["n_points"] == 4
-    assert all(prof[k] >= 0 for k in ("stream_gen", "engine_dispatch",
-                                      "report_solve", "assembly"))
+    assert all(prof[k] >= 0 for k in stages)
     payload = json.loads(rb.to_json())
     assert payload["profile"]["report_solver"] == "batched"
     with pytest.raises(ValueError, match="report"):
