@@ -491,6 +491,19 @@ class SimSpec:
             spec = dataclasses.replace(spec, **{head: new_child})
         return spec
 
+    def stream_signature(self) -> tuple:
+        """Everything the host-side stream preparation reads: the traffic,
+        the shard count and mapping, the window grid, the arrival rate
+        (wall-clock path only) and the fault schedule's remap signature.
+        It is :meth:`cache_signature` without the store, so a grid over
+        store knobs alone shares one stream."""
+        remap = (self.faults.remap_signature() or None
+                 if self.faults is not None else None)
+        return (self.traffic, self.n_shards, self.mapping,
+                self.window_grid(),
+                self.agg_rate() if self.window_dt is not None else None,
+                remap)
+
     def cache_signature(self) -> tuple:
         """Everything the tier-1 counter simulation depends on. Sweep points
         sharing a signature reuse one cache run (queuing params are free).
@@ -503,13 +516,10 @@ class SimSpec:
         joins through its *remap signature* only (shard_down intervals
         reroute arrivals and so change the counters); degrades, outages
         and retry policies are queuing-side and sweep over one cached
-        run."""
-        remap = (self.faults.remap_signature() or None
-                 if self.faults is not None else None)
-        return (self.traffic, self.store, self.n_shards, self.mapping,
-                self.window_grid(),
-                self.agg_rate() if self.window_dt is not None else None,
-                remap)
+        run. The tuple is :meth:`stream_signature` with the store
+        inserted after the traffic."""
+        traffic, *rest = self.stream_signature()
+        return (traffic, self.store, *rest)
 
 
 def _replace_nested(obj, updates: dict):

@@ -10,7 +10,10 @@ Four levels of work sharing make wide sweeps cheap:
 1. **Cache-run dedup** — points that differ only in queuing-side knobs
    (λ, k, flow, rates, p12_override) share a
    :meth:`SimSpec.cache_signature`; the expensive tier-1 counter
-   simulation runs once per signature.
+   simulation runs once per signature. One level up, signatures that
+   differ only in the store share a :meth:`SimSpec.stream_signature`,
+   and the host stream preparation (generation, failover, binning,
+   partition) runs once per stream signature per call.
 2. **Megabatch vmap** — signatures whose *structural* engine is identical
    (same ``StoreConfig.static_config()``, shard count, mapping) stack into
    one ``[point, shard, len]`` batch processed by a single triply-batched
@@ -96,7 +99,7 @@ from repro.sim.engine import (
     tier1_counters,
 )
 from repro.sim.mrc import mrc_tier1_counters, mrc_unsupported_reason
-from repro.sim.spans import span
+from repro.sim.spans import count, span
 from repro.sim.stream import stream_tier1_counters
 from repro.sim.spec import SimSpec
 from repro.storage.tiered_store import (
@@ -163,13 +166,14 @@ class SweepResult:
     reports: tuple         # SimReport per point
     # sweep(profile=True): per-stage wall-clock seconds, one key per
     # repro.sim.spans span — stream_gen (host-side traffic generation +
-    # partitioning for the megabatch; traffic_gen is its generation
-    # alone), engine_dispatch_submit / engine_dispatch_wait (the
-    # megabatch's device engine calls and their gather), route_stream /
-    # route_mrc (the routed chunked-replay and MRC paths, with their own
-    # stream_chunk_* / mrc_* spans), unbatched (batch=False's per-point
-    # engine runs), report_solve (queuing-network solves), assembly
-    # (SimReport construction) and total.
+    # partitioning for the megabatch, once per stream signature;
+    # traffic_gen is its generation alone, and the counter stream_shared
+    # the signatures that reused a stream), engine_dispatch_submit /
+    # engine_dispatch_wait (the megabatch's device engine calls and their
+    # gather), route_stream / route_mrc (the routed chunked-replay and MRC
+    # paths, with their own stream_chunk_* / mrc_* spans), unbatched
+    # (batch=False's per-point engine runs), report_solve (queuing-network
+    # solves), assembly (SimReport construction) and total.
     profile: Optional[dict] = None
 
     def rows(self) -> list[dict]:
@@ -387,18 +391,25 @@ def _batched_engine(
     return fn
 
 
-class _Member(NamedTuple):
-    """One unique cache signature prepared for stacking."""
+class _Stream(NamedTuple):
+    """One stream signature's host preparation, shared read-only by every
+    member with that signature."""
 
-    bucket: int          # power-of-two padded length for this point
-    sig: tuple           # cache signature
-    spec: SimSpec
     sh_pages: np.ndarray  # [S, own_cap] partitioned stream
     sh_writes: np.ndarray
     sh_win: np.ndarray   # [S, own_cap] window ids (n_windows = pad/drop);
                          # timed specs pre-bin arrival times into these
     counts: np.ndarray   # per-shard real request counts
     shard_writes: np.ndarray  # per-shard write counts
+
+
+class _Member(NamedTuple):
+    """One unique cache signature prepared for stacking."""
+
+    bucket: int          # power-of-two padded length for this point
+    sig: tuple           # cache signature
+    spec: SimSpec
+    stream: _Stream
 
 
 @dataclasses.dataclass
@@ -425,10 +436,11 @@ class _PendingBucket:
         return out
 
 
-def _member(spec: SimSpec, sig: tuple, n_shards: int, n_windows: int,
-            timed: bool, prof: Optional[dict]) -> _Member:
-    """One signature's stream made, failed over, binned and partitioned for
-    stacking; its traffic generation alone is the ``traffic_gen`` span."""
+def _stream(spec: SimSpec, n_shards: int, n_windows: int, timed: bool,
+            prof: Optional[dict]) -> _Stream:
+    """A stream made, failed over, binned and partitioned for stacking; its
+    traffic generation alone is the ``traffic_gen`` span. The arrays are
+    read-only: every member of the stream signature shares them."""
     n_windows_i, window_dt = spec.window_grid()
     assert n_windows_i == n_windows  # grouped by batch key
     if timed:
@@ -456,28 +468,44 @@ def _member(spec: SimSpec, sig: tuple, n_shards: int, n_windows: int,
             pages, is_write, n_shards=n_shards, mapping=spec.mapping,
             n_pages=sim_n_pages(spec, pages), n_windows=n_windows,
         )
-    return _Member(
-        bucket=_bucket_cap(sh_p.shape[1]),
-        sig=sig,
-        spec=spec,
-        sh_pages=sh_p,
-        sh_writes=sh_w,
-        sh_win=sh_tw,
-        counts=counts,
-        shard_writes=np.bincount(owner[is_write], minlength=n_shards),
-    )
+    out = _Stream(sh_p, sh_w, sh_tw, counts,
+                  np.bincount(owner[is_write], minlength=n_shards))
+    for arr in out:
+        arr.setflags(write=False)
+    return out
+
+
+def _member(spec: SimSpec, sig: tuple, streams: dict, n_shards: int,
+            n_windows: int, timed: bool, prof: Optional[dict]) -> _Member:
+    """One signature ready for stacking. Its stream is made once per
+    :meth:`SimSpec.stream_signature` per sweep call: ``streams`` holds the
+    ones already made, and a member served from it adds one to the
+    ``stream_shared`` counter instead of opening ``traffic_gen``."""
+    key = spec.stream_signature()
+    stream = streams.get(key)
+    if stream is None:
+        stream = streams[key] = _stream(spec, n_shards, n_windows, timed,
+                                        prof)
+    else:
+        count("stream_shared", prof)
+    return _Member(bucket=_bucket_cap(stream.sh_pages.shape[1]), sig=sig,
+                   spec=spec, stream=stream)
 
 
 def _dispatch_group(
-    specs: list[SimSpec], sigs: list, *, unroll: int, devices: tuple,
-    engine: str = "fused", donate: bool = True,
+    specs: list[SimSpec], sigs: list, streams: dict, *, unroll: int,
+    devices: tuple, engine: str = "fused", donate: bool = True,
     _prof: Optional[dict] = None,
 ) -> list[_PendingBucket]:
     """Partition, bucket, pad and asynchronously dispatch every unique cache
     signature of one batch-key group. Returns pending buckets; device compute
     proceeds while the caller prepares and dispatches later groups.
-    ``_prof`` collects the ``stream_gen`` span (with ``traffic_gen`` per
-    member inside it) and the ``engine_dispatch_submit`` span."""
+    ``streams`` is the sweep call's ``{stream signature: stream}``: a stream
+    is made once per stream signature per call, whichever group first needs
+    it, and shared by every later member (see :func:`_member`). ``_prof``
+    collects the ``stream_gen`` span (with a ``traffic_gen`` span for each
+    stream made inside it), the ``stream_shared`` counter and the
+    ``engine_dispatch_submit`` span."""
     store_static = specs[0].store.static_config()
     n_shards = specs[0].n_shards
     n_windows, window_dt0 = specs[0].window_grid()
@@ -485,7 +513,8 @@ def _dispatch_group(
     n_dev = len(devices)
 
     with span("stream_gen", _prof):
-        members = [_member(spec, sig, n_shards, n_windows, timed, _prof)
+        members = [_member(spec, sig, streams, n_shards, n_windows, timed,
+                           _prof)
                    for spec, sig in zip(specs, sigs)]
 
     # Submission side of the engine stage: host→device transfer of the
@@ -509,14 +538,15 @@ def _dispatch_group(
             # is bit-identical across bucket choices).
             sh_win = np.full((n_pad, n_shards, cap), n_windows, np.int32)
             for i, m in enumerate(group):
-                w = m.sh_pages.shape[1]
+                st = m.stream
+                w = st.sh_pages.shape[1]
                 # Rows come pre-padded with their shard's last page;
                 # extending that edge-repeat keeps the padding a pure-hit
                 # stream.
-                sh_pages[i, :, :w] = m.sh_pages
-                sh_pages[i, :, w:] = m.sh_pages[:, -1:]
-                sh_writes[i, :, :w] = m.sh_writes
-                sh_win[i, :, :w] = m.sh_win
+                sh_pages[i, :, :w] = st.sh_pages
+                sh_pages[i, :, w:] = st.sh_pages[:, -1:]
+                sh_writes[i, :, :w] = st.sh_writes
+                sh_win[i, :, :w] = st.sh_win
             # Padded points: discarded after the gather.
             sh_pages[n:] = sh_pages[0]
             sh_writes[n:] = sh_writes[0]
@@ -542,8 +572,8 @@ def _dispatch_group(
             stats = eng(hyper, *operands)
             pending.append(_PendingBucket(
                 sigs=[m.sig for m in group],
-                counts=[m.counts for m in group],
-                writes=[m.shard_writes for m in group],
+                counts=[m.stream.counts for m in group],
+                writes=[m.stream.shard_writes for m in group],
                 cap=cap,
                 stats=stats,
             ))
@@ -616,7 +646,10 @@ def sweep(
     one key per :func:`repro.sim.spans.span`, each also a ``repro.<key>``
     event in a profiler trace) to :attr:`SweepResult.profile`, serialized
     by ``to_json``: ``stream_gen`` (with ``traffic_gen``, the generation
-    alone, inside it), ``engine_dispatch_submit`` (host-side transfer and
+    alone, inside it; a stream is made once per
+    :meth:`SimSpec.stream_signature` per call, and the integer counter
+    ``stream_shared`` counts the megabatch signatures served by a stream
+    already made), ``engine_dispatch_submit`` (host-side transfer and
     submission of the async megabatch calls), ``engine_dispatch_wait``
     (device compute + gather back to host), ``route_stream`` and
     ``route_mrc`` (the routed chunked-replay and MRC paths, which add
@@ -659,6 +692,8 @@ def sweep(
         prof = dict.fromkeys(
             stages + ("engine_dispatch_submit", "engine_dispatch_wait",
                       "report_solve", "assembly"), 0.0)
+        if batch:
+            prof["stream_shared"] = 0
     with span("total", prof):
         reports = _sweep(specs, batch=batch, unroll=unroll, mrc=mrc,
                          stream=stream, solver=solver, engine=engine,
@@ -706,6 +741,9 @@ def _sweep(specs: list[SimSpec], *, batch: bool, unroll: int, mrc: str,
         # and padding for group k+1 overlap device compute for group k, and
         # the queuing solves below overlap the tail of device compute.
         pending: list[_PendingBucket] = []
+        # One stream per stream signature for the whole call, across batch
+        # groups (a size grid's n_lines buckets share it too).
+        streams: dict[tuple, _Stream] = {}
         for key, sigs in groups.items():
             log.info(
                 "sweep: batch group n_shards=%d, %d signatures "
@@ -713,7 +751,7 @@ def _sweep(specs: list[SimSpec], *, batch: bool, unroll: int, mrc: str,
                 key[1], len(sigs), key[0].n_lines, key[2],
             )
             pending.extend(
-                _dispatch_group([unique[s] for s in sigs], sigs,
+                _dispatch_group([unique[s] for s in sigs], sigs, streams,
                                 unroll=unroll, devices=devices,
                                 engine=engine, donate=donate, _prof=prof)
             )
