@@ -50,6 +50,7 @@ import numpy as np
 
 import jax
 
+from repro.core.online_learning import EXPERTS
 from repro.core.queuing import transient_two_tier
 from repro.core.traffic import TenantStream
 from repro.kernels.backend import XLA, record_paths
@@ -198,7 +199,11 @@ def stream_tier1_counters(
     ``stream_engine`` (from the first chunk's submission to the carry on
     the host), and the counters ``stream_chunks`` (chunks),
     ``stream_scan_steps`` (engine steps: ``cap * n_shards`` a chunk, pads
-    included) and ``stream_requests`` (the chunks' real requests)."""
+    included) and ``stream_requests`` (the chunks' real requests). The
+    learner's work is counted on the host from the carry: over the call,
+    ``stream_evictions`` (evictions, summed over shards) and
+    ``stream_evictions_lru``, ``stream_evictions_lfu`` and
+    ``stream_evictions_random`` (those each expert named)."""
     if chunk < 1:
         raise ValueError("chunk must be >= 1")
     prof = profile
@@ -318,6 +323,9 @@ def stream_tier1_counters(
         # make the checkpoint picklable.
         with span("stream_chunk_wait", prof):
             carry_host = jax.tree.map(np.asarray, carry)
+    if prof is not None:
+        _count_evictions(prof, carry_host[1],
+                         None if checkpoint is None else checkpoint.carry[1])
     stats = stream_stats_from_carry(carry_host, counts)
 
     tenant_ctr = None
@@ -374,6 +382,20 @@ def stream_tier1_counters(
         last_tenant=last_tenant.copy() if tenant else None,
     )
     return ctr, tenant_ctr, ck
+
+
+def _count_evictions(prof: dict, acc, acc0) -> None:
+    """Add the evictions a call made, and each expert's share, to the
+    profile's counters: the accumulators' growth from ``acc0`` (``None``
+    for a fresh replay) to ``acc``, summed over shards."""
+    ev = int(np.sum(acc.evictions))
+    use = np.sum(acc.expert_use, axis=0, dtype=np.int64)
+    if acc0 is not None:
+        ev -= int(np.sum(acc0.evictions))
+        use = use - np.sum(acc0.expert_use, axis=0, dtype=np.int64)
+    count("stream_evictions", prof, ev)
+    for name, n in zip(EXPERTS, use.tolist()):
+        count("stream_evictions_" + name, prof, n)
 
 
 def _frontier_fluid_q0(spec: SimSpec, rep: SimReport) -> Optional[tuple]:
