@@ -9,8 +9,9 @@ Produces repo-root ``BENCH_stream.json`` with:
   schedule straddling chunk boundaries, and a ``tenant_mix`` workload
   whose per-tenant series must reconcile with the pooled windows.
 - ``compile_count``: a fresh >= 32-chunk replay of a >= 1M-request stream
-  (smoke: scaled down) must compile the chunk engine at most twice (the
-  primary and fallback length buckets).
+  (smoke: scaled down) must compile the chunk engine at most twice: at
+  four shards the bucket ladder has two rungs, the primary and the
+  fallback (``repro.sim.stream._chunk_cap``).
 - ``memory``: peak live device bytes sampled across replays of two
   streams 8x apart in length must stay flat (the whole point of
   streaming: footprint is O(chunk), not O(trace)).
@@ -45,7 +46,7 @@ from repro.core.traffic import (  # noqa: E402
 from repro.sim import SimSpec, simulate_stream, stream_tier1_counters  # noqa: E402
 from repro.sim.engine import report_from_counters, tier1_counters  # noqa: E402
 from repro.sim.spec import FaultSpec, StoreConfig, shard_down  # noqa: E402
-from repro.sim.stream import _chunk_caps  # noqa: E402
+from repro.sim.stream import _chunk_cap  # noqa: E402
 from repro.storage.tiered_store import (  # noqa: E402
     init_stream_carry,
     partition_streams,
@@ -58,7 +59,7 @@ from repro.storage.tiered_store import (  # noqa: E402
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ARTIFACT = os.path.join(ROOT, "BENCH_stream.json")
 
-COMPILE_LIMIT = 2       # chunk-engine compiles per replay (two buckets)
+COMPILE_LIMIT = 2       # chunk-engine compiles per replay (rungs at 4 shards)
 MEM_FLAT_RATIO = 1.25   # peak(8x stream) / peak(1x stream) must stay under
 MIN_SPEEDUP = 2.0       # optimized vs naive chunked replay
 LEN_RATIO = 8           # memory gate: long stream / short stream
@@ -170,7 +171,6 @@ def _replay_peak_bytes(cfg: StoreConfig, pages, writes, *, chunk: int,
                        n_shards: int) -> int:
     """Drive the chunk engine directly, sampling live device bytes after
     every (synchronized) chunk — the measured peak of a replay."""
-    primary, fallback = _chunk_caps(chunk, n_shards)
     eng = stream_chunk_engine(cfg, n_windows=1)
     hyper = cfg.hyper()
     carry = init_stream_carry(cfg, n_shards, n_windows=1)
@@ -185,7 +185,7 @@ def _replay_peak_bytes(cfg: StoreConfig, pages, writes, *, chunk: int,
         sh_p, sh_w, _, _, sh_win = partition_streams(
             pages[sl], writes[sl], n_shards=n_shards,
             n_pages=int(pages.max()) + 1,
-            cap=primary if int(cnt.max()) <= primary else fallback,
+            cap=_chunk_cap(int(cnt.max()), chunk, n_shards),
             n_windows=1, window_ids=zeros[:m], owner=own)
         carry = eng(hyper, carry, *jax.device_put((sh_p, sh_w, sh_win)))
         jax.block_until_ready(carry)

@@ -13,10 +13,13 @@ resumable chunk engine
   the carry and chunk buffers are *donated* (``jit(...,
   donate_argnums=...)``) so every chunk reuses the previous chunk's
   allocations. Peak device memory is independent of trace length.
-- **One compile (two shapes max).** Chunks land in one of exactly two
-  per-shard length buckets — a primary bucket sized for balanced shard
-  loads and a fallback sized for the worst skew — so an arbitrarily long
-  replay compiles the engine at most twice
+- **Few compiles (a power-of-two ladder).** A chunk's per-shard length
+  bucket is the power of two that fits its hottest shard, clamped between
+  a primary bucket sized for balanced shard loads and a fallback that fits
+  one shard owning the whole chunk (:func:`_chunk_cap`). The scan's cost is
+  proportional to the bucket, so a skewed chunk pays for its hot shard and
+  no more, and an arbitrarily long replay compiles the engine at most once
+  per rung, ``log2(fallback / primary) + 1`` shapes
   (:func:`repro.storage.tiered_store.stream_compile_count` observes this).
 - **Overlap.** The engine call dispatches asynchronously: host-side
   generation, window binning and partitioning of chunk ``k+1`` overlap
@@ -96,18 +99,22 @@ def _next_pow2(n: int) -> int:
     return cap
 
 
-def _chunk_caps(chunk: int, n_shards: int) -> tuple[int, int]:
-    """The two per-shard length buckets every chunk of a replay lands in.
+def _chunk_cap(hot: int, chunk: int, n_shards: int) -> int:
+    """The per-shard length bucket of a chunk whose hottest shard holds
+    ``hot`` requests: ``next_pow2(hot)``, clamped below at the primary
+    bucket and above at the fallback.
 
     The primary bucket assumes roughly balanced shard loads (2x headroom
-    over ``chunk / n_shards``); a chunk whose worst shard overflows it —
-    pathological mapping skew — takes the fallback bucket, which fits any
-    chunk (one shard owning everything). Two buckets → at most two XLA
-    compiles per replay, no matter how many chunks stream through."""
+    over ``chunk / n_shards``, at least :data:`MIN_CAP`); the fallback fits
+    any chunk (one shard owning everything). Between them lies a ladder of
+    powers of two, so a skewed chunk scans the rung its hot shard needs,
+    never more than the fallback, and a replay compiles the engine at most
+    ``log2(fallback / primary) + 1`` times, no matter how many chunks
+    stream through."""
     fallback = _next_pow2(max(chunk, 1))
     primary = min(_next_pow2(max(MIN_CAP, -(-2 * chunk // n_shards))),
                   fallback)
-    return primary, fallback
+    return min(max(_next_pow2(hot), primary), fallback)
 
 
 @dataclasses.dataclass
@@ -260,7 +267,6 @@ def stream_tier1_counters(
     start = offset
     stop = total if max_requests is None else min(total,
                                                   offset + int(max_requests))
-    primary, fallback = _chunk_caps(chunk, n_shards)
     eng = stream_chunk_engine(spec.store, unroll=unroll,
                               n_windows=eng_windows, donate=donate,
                               engine=engine)
@@ -291,7 +297,7 @@ def stream_tier1_counters(
                     p, w = pages[sl], is_write[sl]
                     own, cwin = owner_all[sl], gwin_all[sl]
                 cnt = np.bincount(own, minlength=n_shards)
-                cap = primary if int(cnt.max()) <= primary else fallback
+                cap = _chunk_cap(int(cnt.max()), chunk, n_shards)
                 sh_p, sh_w, cnt, _, sh_win = partition_streams(
                     p, w, n_shards=n_shards, mapping=spec.mapping,
                     n_pages=n_pages, cap=cap, n_windows=eng_windows,

@@ -32,6 +32,7 @@ from repro.sim import (
     tier1_counters,
 )
 from repro.sim.engine import report_from_counters
+from repro.sim.stream import MIN_CAP, _chunk_cap
 from repro.storage.tiered_store import (
     StoreConfig,
     reset_stream_compile_count,
@@ -279,6 +280,87 @@ class TestCompileCount:
         # More chunkings with the same chunk size: no further compiles.
         stream_tier1_counters(spec, chunk=250, max_requests=999)
         assert stream_compile_count() <= 2
+
+
+def _old_two_bucket_cap(hot, chunk, n_shards):
+    """The rule the ladder replaced: the primary bucket, else the
+    fallback."""
+    fallback = 1 << max(chunk - 1, 0).bit_length()
+    primary = min(1 << (max(MIN_CAP, -(-2 * chunk // n_shards)) - 1)
+                  .bit_length(), fallback)
+    return primary if hot <= primary else fallback
+
+
+def _skewed_trace(n, hot_of_20, *, n_pages=512, n_shards=8, seed=0):
+    """Block-mapped trace whose first shard takes ``hot_of_20`` of every
+    20 requests outright and its share of the rest, spread uniformly over
+    all pages."""
+    rng = np.random.default_rng(seed)
+    per_shard = n_pages // n_shards
+    hot = np.arange(n) % 20 < hot_of_20
+    pages = np.where(hot, rng.integers(0, per_shard, n),
+                     rng.integers(0, n_pages, n)).astype(np.int32)
+    pages[-1] = n_pages - 1       # the trace's page space is all n_pages
+    return pages, rng.random(n) < 0.3
+
+
+class TestBucketLadder:
+    # 8 shards, chunk 2,048: primary 512, rungs 512 / 1,024 / 2,048.
+    CHUNK, N_SHARDS = 2048, 8
+
+    @pytest.mark.parametrize(
+        "policy,n_lines,hot_of_20,n,caps",
+        [
+            ("lru", 40, 0, 4 * 2048, [512] * 4),            # balanced
+            ("lru", 44, 7, 4 * 2048, [1024] * 4),           # skewed
+            ("lru", 52, 7, 3 * 2048 + 600, [1024] * 3 + [512]),   # tail
+            ("lru", 56, 20, 2 * 2048 + 1500, [2048] * 3),   # one shard
+            ("ws", 12, 7, 3 * 2048 + 600, [1024] * 3 + [512]),    # learner
+        ],
+        ids=["balanced", "skewed", "tail", "one_shard", "learner"])
+    def test_chunk_takes_its_hot_shards_rung(self, policy, n_lines,
+                                             hot_of_20, n, caps):
+        trace = _skewed_trace(n, hot_of_20)
+        spec = SimSpec(
+            traffic=TrafficSpec(kind="irm", n_requests=n, n_pages=512,
+                                seed=1),
+            store=StoreConfig(n_lines=n_lines, policy=policy),
+            n_shards=self.N_SHARDS, n_windows=5,
+        )
+        prof: dict = {}
+        reset_stream_compile_count()
+        ctr, _, ck = stream_tier1_counters(spec, trace, chunk=self.CHUNK,
+                                           profile=prof)
+        # A fresh n_lines per case: every rung compiles once, from cold.
+        assert stream_compile_count() == len(set(caps))
+        assert ck.done and prof["stream_chunks"] == len(caps)
+        assert prof["stream_scan_steps"] == sum(caps) * self.N_SHARDS
+        for cap, start in zip(caps, range(0, n, self.CHUNK)):
+            hot = max(np.bincount(
+                trace[0][start:start + self.CHUNK] // 64, minlength=8))
+            assert cap <= _old_two_bucket_cap(hot, self.CHUNK,
+                                              self.N_SHARDS)
+        # Pads are masked no-ops at any bucket: counters and weights are
+        # bit-equal to the one-shot scan and to another chunking's rungs.
+        assert_counters_equal(tier1_counters(spec, trace), ctr)
+        assert_counters_equal(
+            stream_tier1_counters(spec, trace, chunk=2 * self.CHUNK)[0], ctr)
+        if policy == "ws":
+            assert int(np.sum(ctr.evictions)) > 0
+
+    @pytest.mark.parametrize("chunk,n_shards",
+                             [(1 << 18, 16), (2048, 8), (250, 4), (1000, 3),
+                              (1, 1)])
+    def test_no_cap_above_the_two_bucket_rule(self, chunk, n_shards):
+        # Balanced, skewed and one-shard-owns-all hot counts, and every
+        # power-of-two boundary between.
+        hots = {1, -(-chunk // n_shards), chunk}
+        hots |= {min(chunk, 1 << k) + d for k in range(chunk.bit_length())
+                 for d in (0, 1)}
+        for hot in sorted(h for h in hots if 1 <= h <= chunk):
+            cap = _chunk_cap(hot, chunk, n_shards)
+            assert hot <= cap <= _old_two_bucket_cap(hot, chunk, n_shards)
+            assert cap & (cap - 1) == 0
 
 
 def test_timestamp_binning_is_float64():
