@@ -69,37 +69,6 @@ def _report_summary() -> list[tuple]:
     return rows
 
 
-def _engine_summary() -> list[tuple]:
-    """Fused cache-scan engine gates (benchmarks/bench_engine.py)."""
-    path = os.path.join(ROOT, "BENCH_engine.json")
-    if not os.path.exists(path):
-        return [("bench_engine", 0.0,
-                 "not-run (python benchmarks/bench_engine.py)")]
-    with open(path) as f:
-        d = json.load(f)
-    eq, ip, cg, sp = (d["equivalence"], d["interpret_parity"],
-                      d["compile_gate"], d["speedup"])
-    rows = [(
-        "engine_equivalence", 0.0,
-        f"cases={eq['cases']};"
-        f"mismatches={len(eq['mismatched_fields'])};ok={eq['ok']}"),
-        ("engine_interpret_parity", 0.0,
-         f"combos={ip['combos']};"
-         f"mismatches={len(ip['mismatched_fields'])};ok={ip['ok']}"),
-        ("engine_compile_gate", 0.0,
-         f"points={cg['n_points']};compiles={cg['compiles']};"
-         f"limit={cg['limit']};ok={cg['ok']}")]
-    if sp.get("skipped"):
-        rows.append(("engine_speedup", 0.0, "skipped (smoke)"))
-    else:
-        rows.append((
-            "engine_speedup", 0.0,
-            f"fused={sp['fused_points_per_sec']}pts/s;"
-            f"scan={sp['scan_points_per_sec']}pts/s;"
-            f"speedup={sp['speedup']}x;ok={sp['ok']}"))
-    return rows
-
-
 def main() -> None:
     use_compile_cache(ROOT)
     rows: list[tuple] = []
@@ -111,7 +80,6 @@ def main() -> None:
     rows += pt.tables_vii_ix_strong_scaling()
     rows += pt.fig10_read_throughput()
     rows += _report_summary()
-    rows += _engine_summary()
     rows += _dryrun_summary()
     print("name,us_per_call,derived")
     for name, us, derived in rows:

@@ -1,19 +1,19 @@
-"""Backend dispatch for the simulator's Pallas kernels: one switch.
+"""Backend dispatch for the simulator's Pallas kernel: one switch.
 
-Each engine that has a Pallas kernel (the tier-1 cache scan, the
-reuse-distance pass) also has an XLA implementation of the same semantics.
+The reuse-distance pass (:mod:`repro.kernels.reuse_distance`) has a Pallas
+kernel and an XLA implementation of the same semantics.
 :func:`kernel_or_xla` stages both and lets :func:`jax.lax.platform_dependent`
 choose by the platform the enclosing computation is *lowered* for: a TPU
-gets the compiled kernel, every other platform the XLA engine. A CPU-placed
-oracle in a process that also drives a TPU therefore still takes the XLA
-path, and nothing reads an environment variable. Interpret-mode Pallas is
-an explicit ``interpret=True`` argument of each kernel, for tests only.
+gets the compiled kernel, every other platform the XLA implementation. A
+CPU-placed oracle in a process that also drives a TPU therefore still takes
+the XLA path, and nothing reads an environment variable. Interpret-mode
+Pallas is an explicit ``interpret=True`` argument of the kernel, for tests
+only.
 
 Each branch also returns its path id (:data:`PALLAS` or :data:`XLA`) as an
 int32 result, so what ran is read back from the outputs rather than
-assumed: the callers hand the ids to :func:`record_paths`, and
-:func:`engine_path_counts` reports how many calls (stream rows for the
-cache scan, distance passes for the reuse-distance kernel) took each path.
+assumed: the caller hands the ids to :func:`record_paths`, and
+:func:`engine_path_counts` reports how many distance passes took each path.
 """
 from __future__ import annotations
 

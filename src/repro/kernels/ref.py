@@ -1,8 +1,12 @@
-"""Pure-jnp oracles for every Pallas kernel (shape-for-shape references).
+"""Pure-jnp oracles for every Pallas kernel (shape-for-shape references),
+and the tier-1 cache-scan engine.
 
-These are deliberately naive (full softmax, materialized scores, sequential
-scans) — correctness baselines for the interpret-mode kernel tests, NOT the
-production XLA path (that is ``models/attention.py`` etc.).
+The oracles are deliberately naive (full softmax, materialized scores,
+sequential scans) — correctness baselines for the interpret-mode kernel
+tests, NOT the production XLA path (that is ``models/attention.py`` etc.).
+The exception is :func:`cache_scan_ref`: it has no kernel and is the tier-1
+request loop on every platform, tested bit for bit against the per-step
+reference :func:`repro.storage.tiered_store.run_stream_reference`.
 """
 from __future__ import annotations
 
@@ -19,6 +23,7 @@ __all__ = [
     "paged_attention_ref",
     "page_copy_ref",
     "reuse_distance_ref",
+    "NOISE_TABLE_MAX",
     "cache_scan_noise",
     "cache_scan_ref",
     "fused_cache_step",
@@ -162,16 +167,22 @@ def reuse_distance_ref(
 
 
 # ---------------------------------------------------------------------------
-# Fused tier-1 cache scan (oracle + production CPU fallback).
+# Fused tier-1 cache scan: the tier-1 engine on every platform.
 #
 # One request step of the storage engine with every scatter/gather replaced
 # by one-hot iota-compare updates — elementwise selects and adds on [N]
-# arrays, the exact op mix the Pallas kernel runs on its VMEM-resident
-# state. Bit-identical to repro.storage.tiered_store._step: integer/bool
+# arrays. Bit-identical to repro.storage.tiered_store._step: integer/bool
 # updates are exact by construction (a one-hot where() IS a single-index
 # scatter), and the float expert-weight arithmetic calls the same
 # online_learning routines (same op order, same f32 rounding).
 # ---------------------------------------------------------------------------
+
+# Noise-table cap, elements. A one-shot row whose [len, n_lines] f32
+# Random-expert table would exceed it draws in the loop instead (the same
+# draws). The table is one array per compiled program (a constant under the
+# megabatch's vmaps) and is materialised whole, on a CPU host in host
+# memory: 2^22 elements are 16 MiB.
+NOISE_TABLE_MAX = 1 << 22
 
 
 def cache_scan_noise(key: jax.Array, length: int, n_lines: int) -> jnp.ndarray:
@@ -451,11 +462,10 @@ def cache_scan_ref(state0, acc0, pages, writes, win, hyper, noise, *,
                    epoch_width: int, pred_cap: int, prefetch: bool,
                    prefetch_width: int, n_windows: int, unroll: int = 1,
                    masked: bool = False):
-    """One stream row of the fused cache engine, pure jnp — the oracle for
-    the Pallas ``cache_scan`` kernel's golden tests AND the production CPU
-    fallback (same pattern as :func:`reuse_distance_ref`; the sequential
-    dependence means the scan stays a scan — ``unroll`` is the blocking
-    knob here, chunking the loop body like the reference engine's).
+    """One stream row of the fused cache engine, pure jnp — the tier-1
+    engine on every platform (the sequential dependence means the scan
+    stays a scan — ``unroll`` is the blocking knob here, chunking the loop
+    body like the reference engine's).
 
     ``noise`` is the precomputed ``[len, n_lines]`` Random-expert table
     (:func:`cache_scan_noise` — the one-shot megabatch mode; ``state0.key``

@@ -416,14 +416,11 @@ def stream_for_spec(spec: SimSpec, trace=None):
     return pages, is_write, times, n_pages, n_windows, window_dt
 
 
-def tier1_counters(spec: SimSpec, trace=None, *,
-                   engine: str = "fused") -> Tier1Counters:
+def tier1_counters(spec: SimSpec, trace=None) -> Tier1Counters:
     """Run the workload through the distributed tier-1 cache
     (:func:`repro.storage.tiered_store.run_distributed`) and return exact
     per-shard counters (whole-stream and per-window). ``trace`` overrides
-    the generated stream (see :func:`stream_for_spec`); ``engine`` selects
-    the fused cache-scan engine (default) or the original ``"scan"``
-    reference it is bit-exact against."""
+    the generated stream (see :func:`stream_for_spec`)."""
     pages, is_write, times, n_pages, n_windows, window_dt = stream_for_spec(
         spec, trace)
     owner = fault_owner(spec, pages, times, n_pages)
@@ -431,7 +428,7 @@ def tier1_counters(spec: SimSpec, trace=None, *,
         spec.store, pages, is_write,
         n_shards=spec.n_shards, mapping=spec.mapping, n_pages=n_pages,
         n_windows=n_windows, timestamps=times, window_dt=window_dt,
-        owner=owner, engine=engine,
+        owner=owner,
     )
     writes = np.bincount(owner[is_write], minlength=spec.n_shards)
     return _assemble_counters(stats, counts, writes)

@@ -56,7 +56,6 @@ import jax
 from repro.core.online_learning import EXPERTS
 from repro.core.queuing import transient_two_tier
 from repro.core.traffic import TenantStream
-from repro.kernels.backend import XLA, record_paths
 from repro.sim.engine import (
     SimReport,
     TenantCounters,
@@ -175,7 +174,6 @@ def stream_tier1_counters(
     checkpoint: Optional[StreamCheckpoint] = None,
     max_requests: Optional[int] = None,
     donate: bool = True,
-    engine: str = "fused",
     profile: Optional[dict] = None,
 ):
     """Chunked-replay counterpart of :func:`repro.sim.engine.tier1_counters`.
@@ -193,8 +191,7 @@ def stream_tier1_counters(
     ``max_requests`` bounds how many further requests this call consumes
     (``None`` = run to the end). ``donate=False`` disables buffer donation
     and async overlap — the naive baseline the benchmarks compare
-    against. ``engine`` selects the fused cache-scan request loop
-    (default) or the original ``"scan"`` reference (bit-exact either way).
+    against.
 
     ``profile`` (a mutable dict) accumulates the replay's stage spans
     (:func:`repro.sim.spans.span`, seconds): ``stream_resume_prep`` (the
@@ -268,8 +265,7 @@ def stream_tier1_counters(
     stop = total if max_requests is None else min(total,
                                                   offset + int(max_requests))
     eng = stream_chunk_engine(spec.store, unroll=unroll,
-                              n_windows=eng_windows, donate=donate,
-                              engine=engine)
+                              n_windows=eng_windows, donate=donate)
     hyper = spec.store.hyper()
 
     # stream_engine opens at the first chunk's submission and closes with
@@ -313,9 +309,6 @@ def stream_tier1_counters(
             with span("stream_chunk_dispatch", prof):
                 dev = jax.device_put((sh_p, sh_w, sh_win))
                 carry = eng(hyper, carry, *dev)
-                # The resumable chunk mode has one implementation, the XLA
-                # engine.
-                record_paths("cache_scan", np.full(n_shards, XLA))
             with span("stream_chunk_wait", prof):
                 if not donate:
                     jax.block_until_ready(carry)
@@ -437,7 +430,6 @@ def simulate_stream(
     checkpoint: Optional[StreamCheckpoint] = None,
     max_requests: Optional[int] = None,
     donate: bool = True,
-    engine: str = "fused",
     profile: Optional[dict] = None,
 ):
     """Streaming counterpart of :func:`repro.sim.engine.simulate`.
@@ -457,8 +449,7 @@ def simulate_stream(
     call runs to the end of the stream and returns the report alone."""
     ctr, tenant_ctr, ck = stream_tier1_counters(
         spec, trace, chunk=chunk, unroll=unroll, checkpoint=checkpoint,
-        max_requests=max_requests, donate=donate, engine=engine,
-        profile=profile)
+        max_requests=max_requests, donate=donate, profile=profile)
     rep = report_from_counters(spec, ctr, tenants=tenant_ctr)
     if max_requests is None:
         return rep

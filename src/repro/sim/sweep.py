@@ -52,7 +52,7 @@ pass over the stream. When a grid axis varies only the cache size and the
 spec sits inside the exact stack-distance domain (LRU, no prefetch — see
 :func:`repro.sim.mrc.mrc_unsupported_reason`), the whole size axis is
 served by :func:`repro.sim.mrc.mrc_tier1_counters` instead: one distance
-pass, zero engine compiles, counters bit-identical to the scan engine.
+pass, zero engine compiles, counters bit-identical to the tier-1 engine.
 ``mrc="auto"`` (default) routes eligible multi-size groups and falls back
 to the engine with a logged reason otherwise; ``"off"`` disables the
 path; ``"require"`` raises ``ValueError`` if any group cannot be routed
@@ -63,7 +63,7 @@ traces on device, so a grid point with a multi-million-request stream
 (or a ``tenant_mix`` workload, whose per-tenant attribution only the
 streaming path produces) is better served by the chunked replay engine
 (:mod:`repro.sim.stream`): bounded device memory, at most two compiles,
-counters bit-identical to the scan. ``stream="auto"`` (default) routes
+counters bit-identical to the megabatch. ``stream="auto"`` (default) routes
 ``tenant_mix`` signatures and streams longer than
 :data:`STREAM_THRESHOLD` requests; ``"off"`` forces everything through
 the megabatch.
@@ -85,7 +85,6 @@ from jax.sharding import PartitionSpec
 
 from repro.core.queuing import fluid_compile_count, reset_fluid_compile_count
 from repro.core.traffic import make_stream, make_timed_stream
-from repro.kernels.backend import record_paths
 from repro.launch.compat import device_mesh, shard_map
 from repro.sim.engine import (
     SimReport,
@@ -106,7 +105,7 @@ from repro.storage.tiered_store import (
     StoreConfig,
     StoreHyper,
     partition_streams,
-    run_stream_path,
+    run_stream,
     timestamp_window_ids,
 )
 
@@ -238,7 +237,7 @@ def _route_mrc(
 ) -> dict[tuple, Tier1Counters]:
     """Serve every eligible size-only signature group via the one-pass MRC
     engine. Returns ``{signature: counters}`` for the routed signatures
-    (bit-identical to the scan engine); the caller runs the rest through
+    (bit-identical to the tier-1 engine); the caller runs the rest through
     the batched engine. ``mrc="require"`` raises if any group is
     ineligible; ``"auto"`` routes only groups with >= 2 sizes (a single
     size gains nothing over the engine). ``profile`` collects the MRC
@@ -259,7 +258,7 @@ def _route_mrc(
                 )
             if len(sigs) >= 2:
                 log.info(
-                    "sweep: MRC fallback to scan engine for %d sizes (%s)",
+                    "sweep: MRC fallback to the tier-1 engine for %d sizes (%s)",
                     len(sigs), reason,
                 )
             continue
@@ -279,11 +278,11 @@ def _route_mrc(
 
 def _route_stream(
     unique: Mapping[tuple, SimSpec], stream: str, *,
-    engine: str = "fused", profile: Optional[dict] = None,
+    profile: Optional[dict] = None,
 ) -> tuple[dict[tuple, Tier1Counters], dict[tuple, TenantCounters]]:
     """Serve ``tenant_mix`` and oversized-stream signatures via the chunked
     replay engine (:mod:`repro.sim.stream`): bounded device memory, at most
-    two compiles, counters bit-identical to the scan engine. Returns
+    two compiles, counters bit-identical to the megabatch. Returns
     ``({signature: counters}, {signature: tenant_counters})`` for the
     routed signatures; the caller runs the rest through the megabatch.
     ``profile`` threads per-chunk sub-timings through to
@@ -301,8 +300,7 @@ def _route_stream(
             "tenant_mix" if mix else "oversized stream",
             spec.traffic.n_requests,
         )
-        ctr, tc, _ = stream_tier1_counters(spec, engine=engine,
-                                           profile=profile)
+        ctr, tc, _ = stream_tier1_counters(spec, profile=profile)
         counters[sig] = ctr
         if tc is not None:
             tenants[sig] = tc
@@ -325,24 +323,22 @@ def _stack_hypers(stores: Sequence[StoreConfig]) -> StoreHyper:
 
 def _batched_engine(
     store: StoreConfig, unroll: int, devices: tuple, n_windows: int,
-    engine: str = "fused", donate: bool = True,
+    donate: bool = True,
 ) -> Callable:
     """The one-compile megabatch engine for a structural store config:
     ``(hyper [N], pages [N, S, L], writes [N, S, L], win [N, S, L]) ->
-    (StreamStats [N, S], engine path ids [N, S])`` (windowed counters
-    ``[N, S, n_windows]``), point
-    axis sharded over ``devices``. Wall-clock specs feed the same
-    ``win`` operand (arrival times become int32 ids host-side), so timed
-    and request-index grids share this one engine. Cached so repeated
+    StreamStats [N, S]`` (windowed counters ``[N, S, n_windows]``), point
+    axis sharded over ``devices``. Each row runs
+    :func:`repro.storage.tiered_store.run_stream`. Wall-clock specs feed
+    the same ``win`` operand (arrival times become int32 ids host-side), so
+    timed and request-index grids share this one engine. Cached so repeated
     sweeps reuse both the wrapper and jit's compile cache.
 
-    ``engine`` selects the request-loop implementation (see
-    :func:`repro.storage.tiered_store.run_stream`); ``donate=True``
-    donates the three stacked chunk buffers to the dispatch
+    ``donate=True`` donates the three stacked chunk buffers to the dispatch
     (``donate_argnums``) so XLA may recycle their allocations while the
     engine runs — ``donate=False`` keeps the undonated baseline
     available (buffers stay valid after the call)."""
-    key = (store, unroll, devices, n_windows, engine, donate)
+    key = (store, unroll, devices, n_windows, donate)
     fn = _ENGINE_CACHE.get(key)
     if fn is not None:
         return fn
@@ -353,9 +349,9 @@ def _batched_engine(
 
         def point(h, p, w, wi):
             return jax.vmap(
-                lambda pp, ww, wwi: run_stream_path(
+                lambda pp, ww, wwi: run_stream(
                     store, pp, ww, hyper=h, unroll=unroll,
-                    n_windows=n_windows, window_ids=wwi, engine=engine,
+                    n_windows=n_windows, window_ids=wwi,
                 )
             )(p, w, wi)
 
@@ -420,13 +416,11 @@ class _PendingBucket:
     counts: list         # per-point per-shard real request counts
     writes: list         # per-point per-shard write counts
     cap: int             # padded stream length (bucket)
-    stats: object        # (StreamStats, engine path ids) of device arrays
-                         # (async futures)
+    stats: object        # StreamStats of device arrays (async futures)
 
     def gather(self) -> dict:
         # Blocks on the device.
-        stacked, paths = jax.tree.map(np.asarray, self.stats)
-        record_paths("cache_scan", paths[:len(self.sigs)])
+        stacked = jax.tree.map(np.asarray, self.stats)
         out = {}
         for i, sig in enumerate(self.sigs):
             stats_i = jax.tree.map(lambda a: a[i], stacked)
@@ -494,8 +488,7 @@ def _member(spec: SimSpec, sig: tuple, streams: dict, n_shards: int,
 
 def _dispatch_group(
     specs: list[SimSpec], sigs: list, streams: dict, *, unroll: int,
-    devices: tuple, engine: str = "fused", donate: bool = True,
-    _prof: Optional[dict] = None,
+    devices: tuple, donate: bool = True, _prof: Optional[dict] = None,
 ) -> list[_PendingBucket]:
     """Partition, bucket, pad and asynchronously dispatch every unique cache
     signature of one batch-key group. Returns pending buckets; device compute
@@ -556,7 +549,7 @@ def _dispatch_group(
             hyper = _stack_hypers(stores)
 
             eng = _batched_engine(store_static, unroll, devices, n_windows,
-                                  engine, donate)
+                                  donate)
             log.info(
                 "sweep: dispatch %d points x %d shards @ len %d "
                 "(n_lines=%d, windows=%d, timed=%s, devices=%d)",
@@ -589,7 +582,6 @@ def sweep(
     mrc: str = "auto",
     stream: str = "auto",
     report: str = "auto",
-    engine: str = "fused",
     donate: bool = True,
     profile: bool = False,
     verbose: bool = False,
@@ -611,7 +603,7 @@ def sweep(
     module docstring): ``"auto"`` serves eligible size-only groups from
     one stack-distance pass, ``"off"`` always scans, ``"require"`` raises
     ``ValueError`` when the MRC path cannot serve the grid (incompatible
-    with ``batch=False``, whose purpose is the reference scan).
+    with ``batch=False``, whose purpose is the unbatched engine run).
 
     ``stream`` controls chunked-replay routing (see module docstring):
     ``"auto"`` serves ``tenant_mix`` signatures (adding per-tenant
@@ -628,14 +620,9 @@ def sweep(
     pre-batching per-point path; ``"auto"`` follows ``batch``. Batched and
     scalar reports agree to ~1e-13 (analytic k=1 path).
 
-    ``engine`` selects the tier-1 request-loop implementation
-    (:func:`repro.storage.tiered_store.run_stream`): ``"fused"`` (default)
-    is the fused cache-scan engine, ``"pallas"`` the same with its Pallas
-    kernel where the computation is lowered for a TPU, ``"scan"`` the
-    original per-step reference both are bit-exact against. ``donate=True``
-    donates the stacked
-    stream buffers to each megabatch dispatch (``donate_argnums``);
-    ``donate=False`` keeps the undonated baseline.
+    ``donate=True`` donates the stacked stream buffers to each megabatch
+    dispatch (``donate_argnums``); ``donate=False`` keeps the undonated
+    baseline.
 
     ``devices`` are the devices the megabatch's point axis is sharded
     over (default: every local device); one device runs it unsharded
@@ -668,7 +655,7 @@ def sweep(
     if mrc == "require" and not batch:
         raise ValueError(
             "mrc='require' is incompatible with batch=False: the unbatched "
-            "path exists as the scan-engine reference")
+            "path exists as the unbatched engine run")
     if verbose:
         # Convenience for interactive use: make this module's INFO progress
         # lines visible regardless of how (or whether) the app configured
@@ -696,8 +683,8 @@ def sweep(
             prof["stream_shared"] = 0
     with span("total", prof):
         reports = _sweep(specs, batch=batch, unroll=unroll, mrc=mrc,
-                         stream=stream, solver=solver, engine=engine,
-                         donate=donate, devices=devices, prof=prof)
+                         stream=stream, solver=solver, donate=donate,
+                         devices=devices, prof=prof)
     if prof is not None:
         prof["n_points"] = len(points)
         prof["report_solver"] = solver
@@ -711,7 +698,7 @@ def sweep(
 
 
 def _sweep(specs: list[SimSpec], *, batch: bool, unroll: int, mrc: str,
-           stream: str, solver: str, engine: str, donate: bool,
+           stream: str, solver: str, donate: bool,
            devices: tuple, prof: Optional[dict]) -> list[SimReport]:
     """:func:`sweep`'s work once its grid is expanded: a report per spec."""
     # One cache run per unique signature.
@@ -726,7 +713,7 @@ def _sweep(specs: list[SimSpec], *, batch: bool, unroll: int, mrc: str,
         # The routed paths generate their streams internally.
         with span("route_stream", prof):
             counters, tenant_ctrs = _route_stream(unique, stream,
-                                                  engine=engine, profile=prof)
+                                                  profile=prof)
         if mrc != "off":
             with span("route_mrc", prof):
                 counters.update(_route_mrc(
@@ -753,7 +740,7 @@ def _sweep(specs: list[SimSpec], *, batch: bool, unroll: int, mrc: str,
             pending.extend(
                 _dispatch_group([unique[s] for s in sigs], sigs, streams,
                                 unroll=unroll, devices=devices,
-                                engine=engine, donate=donate, _prof=prof)
+                                donate=donate, _prof=prof)
             )
         # Gather blocks on device compute: the wait side of the engine
         # stage (device compute + device→host transfer).
@@ -764,7 +751,7 @@ def _sweep(specs: list[SimSpec], *, batch: bool, unroll: int, mrc: str,
         with span("unbatched", prof):
             for sig, spec in unique.items():
                 log.info("sweep: run %s", sig)
-                counters[sig] = tier1_counters(spec, engine=engine)
+                counters[sig] = tier1_counters(spec)
 
     return batched_reports(
         [(spec, counters[sig], tenant_ctrs.get(sig))

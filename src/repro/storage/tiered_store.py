@@ -21,6 +21,12 @@ distributed cache shards (paper's per-process caches). Tier-2 is counted
 here (reads / write-backs); converting counts to time is the queuing and
 device-model layer (:mod:`repro.core.queuing`, :mod:`repro.core.device_models`).
 
+**One engine, one reference.** Every path (one-shot rows, the sweep's
+megabatch, the chunked replay) runs its request loop through
+:func:`repro.kernels.ref.cache_scan_ref`. The per-step scan it was derived
+from (:func:`_step`, :func:`_fold`) is kept as :func:`run_stream_reference`,
+the reference the engine is tested against bit for bit.
+
 **Windowed telemetry.** The scan folds every per-request outcome into
 ``n_windows`` accumulator slots carried through the loop (scatter-add by the
 request's time-window id) instead of materializing ``[T]`` per-request
@@ -55,9 +61,12 @@ import numpy as np
 from repro.core import online_learning as ol
 from repro.core import prefetch as pfm
 from repro.core.mapping import page_to_shard
-from repro.kernels.backend import XLA, record_paths
-from repro.kernels.cache_scan import fused_cache_scan
-from repro.kernels.ref import cache_scan_ref, vary_like
+from repro.kernels.ref import (
+    NOISE_TABLE_MAX,
+    cache_scan_noise,
+    cache_scan_ref,
+    vary_like,
+)
 from repro.storage.cache_state import CacheState, init_cache
 
 __all__ = [
@@ -66,7 +75,7 @@ __all__ = [
     "StoreState",
     "StreamStats",
     "run_stream",
-    "run_stream_path",
+    "run_stream_reference",
     "run_stream_chunked",
     "run_distributed",
     "partition_streams",
@@ -86,15 +95,6 @@ __all__ = [
 WS_POLICY_IDX = -1
 POLICY_TO_IDX = {"ws": WS_POLICY_IDX,
                  **{name: i for i, name in enumerate(ol.EXPERTS)}}
-
-# Request-loop implementations (see run_stream).
-ENGINES = ("fused", "pallas", "scan")
-
-
-def _check_engine(engine: str) -> None:
-    if engine not in ENGINES:
-        raise ValueError(
-            f"unknown engine {engine!r}; options: {', '.join(ENGINES)}")
 
 
 class StoreHyper(NamedTuple):
@@ -459,78 +459,11 @@ def timestamp_window_ids(times: np.ndarray, n_windows: int,
     return np.where(t >= 0, ids, n_windows).astype(np.int32)
 
 
-def run_stream(
-    cfg: StoreConfig,
-    pages: jnp.ndarray,
-    is_write: jnp.ndarray,
-    *,
-    seed: int = 0,
-    hyper: Optional[StoreHyper] = None,
-    unroll: int = 1,
-    n_windows: int = 1,
-    window_ids: Optional[jnp.ndarray] = None,
-    timestamps: Optional[jnp.ndarray] = None,
-    window_dt=None,
-    engine: str = "fused",
-) -> StreamStats:
-    """Process a request stream through one tier-1 shard. Jitted scan.
-
-    ``hyper`` overrides the scalar learning knobs of ``cfg`` with (possibly
-    traced) :class:`StoreHyper` operands — the sweep engine's third vmap
-    axis. When traced hypers are supplied, only ``cfg.static_config()``
-    shapes the computation. ``unroll`` chunks the per-request scan body
-    (semantics-preserving; larger values trade compile time for fewer loop
-    iterations on wide batches).
-
-    ``engine`` selects the request-loop implementation (one of
-    :data:`ENGINES`): ``"fused"`` (the default) routes through
-    :func:`repro.kernels.cache_scan.fused_cache_scan` — one-hot elementwise
-    state updates with hoisted Random-expert draws, the XLA engine on every
-    platform; ``"pallas"`` is the same call with the VMEM-resident Pallas
-    kernel where the computation is lowered for a TPU (the engine-path rule
-    in :mod:`repro.kernels.cache_scan`); ``"scan"`` keeps the original
-    per-step gather/scatter ``lax.scan``, the golden reference both are
-    bit-exact against.
-
-    ``n_windows`` resolves the counters over time windows (carried
-    accumulators — O(n_windows) memory, no per-request outputs). The window
-    of a request is, in precedence order:
-
-    - its wall-clock time bin ``t // window_dt`` when ``timestamps``
-      (f32[T] arrival seconds, ``-1`` marking padding) and ``window_dt``
-      are given — both are *data* operands (traced, so one compile serves
-      any timestamp layout and window duration; only ``n_windows`` is
-      structural), and arrivals past ``n_windows * window_dt`` clip into
-      the last bin;
-    - an explicit ``window_ids`` assignment (int32[T], values in
-      [0, n_windows]; ``n_windows`` marks padding, dropped from the
-      windowed counters);
-    - by default, equal request-count slices of this stream's own length.
-    """
-    return run_stream_path(
-        cfg, pages, is_write, seed=seed, hyper=hyper, unroll=unroll,
-        n_windows=n_windows, window_ids=window_ids, timestamps=timestamps,
-        window_dt=window_dt, engine=engine)[0]
-
-
-def run_stream_path(
-    cfg: StoreConfig,
-    pages: jnp.ndarray,
-    is_write: jnp.ndarray,
-    *,
-    seed: int = 0,
-    hyper: Optional[StoreHyper] = None,
-    unroll: int = 1,
-    n_windows: int = 1,
-    window_ids: Optional[jnp.ndarray] = None,
-    timestamps: Optional[jnp.ndarray] = None,
-    window_dt=None,
-    engine: str = "fused",
-):
-    """:func:`run_stream` plus the engine that ran the request loop:
-    ``(stats, path)``, ``path`` the int32 :data:`~repro.kernels.backend.
-    PALLAS` / ``XLA`` id, which callers count with
-    :func:`~repro.kernels.backend.record_paths`."""
+def _window_operands(cfg, pages, is_write, hyper, n_windows, window_ids,
+                     timestamps, window_dt):
+    """The one-shot engines' operands: ``(pages, is_write, hyper,
+    window_ids)`` as device arrays, the window of each request resolved as
+    :func:`run_stream` describes."""
     pages = jnp.asarray(pages, jnp.int32)
     is_write = jnp.asarray(is_write, bool)
     if hyper is None:
@@ -545,30 +478,14 @@ def run_stream_path(
         window_ids = jnp.where(ts >= 0, ids, n_windows)
     elif window_ids is None:
         window_ids = stream_window_ids(pages.shape[0], n_windows)
-    window_ids = jnp.asarray(window_ids, jnp.int32)
-    _check_engine(engine)
+    return pages, is_write, hyper, jnp.asarray(window_ids, jnp.int32)
 
-    carry0 = (init_store(cfg, seed), _init_accum(n_windows))
-    if engine != "scan":
-        weights, acc, path = fused_cache_scan(
-            cfg, hyper, carry0[0], carry0[1], pages, is_write, window_ids,
-            n_windows=n_windows, unroll=unroll, pallas=engine == "pallas",
-        )
-    else:
-        def scan_fn(carry, req):
-            state, acc = carry
-            page, write, win = req
-            state, out = _step(cfg, hyper, state, (page, write),
-                               real=win < n_windows)
-            return (state, _fold(acc, out, win, state.ols.weights)), None
 
-        (final, acc), _ = jax.lax.scan(
-            scan_fn, vary_like(carry0, pages), (pages, is_write, window_ids),
-            unroll=unroll,
-        )
-        weights, path = final.ols.weights, jnp.int32(XLA)
+def _stream_stats(requests, acc: _Accum, weights) -> StreamStats:
+    """:class:`StreamStats` from an engine's accumulators and final expert
+    weights."""
     return StreamStats(
-        requests=pages.shape[0] + jnp.zeros((), jnp.int32),
+        requests=jnp.asarray(requests, jnp.int32),
         hits=acc.hits,
         misses=acc.misses,
         prefetch_hits=acc.prefetch_hits,
@@ -586,13 +503,103 @@ def run_stream_path(
         win_evictions=acc.win_evictions,
         win_expert_use=acc.win_expert_use,
         win_weights=acc.win_weights,
-    ), path
+    )
 
 
-run_stream_jit = jax.jit(
-    run_stream, static_argnums=0,
-    static_argnames=("seed", "unroll", "n_windows", "engine"),
-)
+def run_stream(
+    cfg: StoreConfig,
+    pages: jnp.ndarray,
+    is_write: jnp.ndarray,
+    *,
+    seed: int = 0,
+    hyper: Optional[StoreHyper] = None,
+    unroll: int = 1,
+    n_windows: int = 1,
+    window_ids: Optional[jnp.ndarray] = None,
+    timestamps: Optional[jnp.ndarray] = None,
+    window_dt=None,
+) -> StreamStats:
+    """Process a request stream through one tier-1 shard. Jitted scan.
+
+    The request loop is :func:`repro.kernels.ref.cache_scan_ref`, the
+    tier-1 engine on every platform: one-hot elementwise state updates,
+    with the Random expert's draws hoisted into a
+    :func:`~repro.kernels.ref.cache_scan_noise` table when the row's
+    ``length * n_lines`` fits :data:`~repro.kernels.ref.NOISE_TABLE_MAX`
+    and drawn in the loop otherwise (the same draws either way). It is
+    bit-exact against :func:`run_stream_reference`.
+
+    ``hyper`` overrides the scalar learning knobs of ``cfg`` with (possibly
+    traced) :class:`StoreHyper` operands — the sweep engine's third vmap
+    axis. When traced hypers are supplied, only ``cfg.static_config()``
+    shapes the computation. ``unroll`` chunks the per-request scan body
+    (semantics-preserving; larger values trade compile time for fewer loop
+    iterations on wide batches).
+
+    ``n_windows`` resolves the counters over time windows (carried
+    accumulators — O(n_windows) memory, no per-request outputs). The window
+    of a request is, in precedence order:
+
+    - its wall-clock time bin ``t // window_dt`` when ``timestamps``
+      (f32[T] arrival seconds, ``-1`` marking padding) and ``window_dt``
+      are given — both are *data* operands (traced, so one compile serves
+      any timestamp layout and window duration; only ``n_windows`` is
+      structural), and arrivals past ``n_windows * window_dt`` clip into
+      the last bin;
+    - an explicit ``window_ids`` assignment (int32[T], values in
+      [0, n_windows]; ``n_windows`` marks padding, dropped from the
+      windowed counters);
+    - by default, equal request-count slices of this stream's own length.
+    """
+    pages, is_write, hyper, window_ids = _window_operands(
+        cfg, pages, is_write, hyper, n_windows, window_ids, timestamps,
+        window_dt)
+    state0 = init_store(cfg, seed)
+    length = pages.shape[0]
+    noise = (cache_scan_noise(state0.key, length, cfg.n_lines)
+             if length * cfg.n_lines <= NOISE_TABLE_MAX else None)
+    final, acc = cache_scan_ref(
+        state0, _init_accum(n_windows), pages, is_write, window_ids, hyper,
+        noise, epoch_width=cfg.epoch_width, pred_cap=cfg.pred_cap,
+        prefetch=cfg.prefetch, prefetch_width=cfg.prefetch_width,
+        n_windows=n_windows, unroll=unroll)
+    return _stream_stats(length, acc, final.ols.weights)
+
+
+def run_stream_reference(
+    cfg: StoreConfig,
+    pages: jnp.ndarray,
+    is_write: jnp.ndarray,
+    *,
+    seed: int = 0,
+    hyper: Optional[StoreHyper] = None,
+    n_windows: int = 1,
+    window_ids: Optional[jnp.ndarray] = None,
+    timestamps: Optional[jnp.ndarray] = None,
+    window_dt=None,
+    unroll: int = 1,
+) -> StreamStats:
+    """The per-step reference the tier-1 engine is tested against:
+    :func:`run_stream`'s arguments and result, computed by the original
+    ``lax.scan`` over :func:`_step` (gathers, scatters and an in-loop PRNG
+    split per request) and :func:`_fold`. No production path calls it."""
+    pages, is_write, hyper, window_ids = _window_operands(
+        cfg, pages, is_write, hyper, n_windows, window_ids, timestamps,
+        window_dt)
+
+    def scan_fn(carry, req):
+        state, acc = carry
+        page, write, win = req
+        state, out = _step(cfg, hyper, state, (page, write),
+                           real=win < n_windows)
+        return (state, _fold(acc, out, win, state.ols.weights)), None
+
+    carry0 = (init_store(cfg, seed), _init_accum(n_windows))
+    (final, acc), _ = jax.lax.scan(
+        scan_fn, vary_like(carry0, pages), (pages, is_write, window_ids),
+        unroll=unroll,
+    )
+    return _stream_stats(pages.shape[0], acc, final.ols.weights)
 
 
 def partition_streams(
@@ -764,7 +771,6 @@ def run_distributed(
     timestamps: Optional[np.ndarray] = None,
     window_dt: Optional[float] = None,
     owner: Optional[np.ndarray] = None,
-    engine: str = "fused",
 ):
     """Distributed tier-1 cache: requests partitioned to per-shard caches by
     the §III mapping policy, shards processed by ``vmap`` (the paper's
@@ -796,13 +802,10 @@ def run_distributed(
             pages, is_write, n_shards=n_shards, mapping=mapping,
             n_pages=n_pages, n_windows=n_windows, owner=owner,
         )
-    stats, paths = jax.vmap(
-        lambda p, w, wi: run_stream_path(
-            cfg, p, w, seed=seed, n_windows=n_windows, window_ids=wi,
-            engine=engine,
-        )
+    stats = jax.vmap(
+        lambda p, w, wi: run_stream(
+            cfg, p, w, seed=seed, n_windows=n_windows, window_ids=wi)
     )(jnp.asarray(sh_pages), jnp.asarray(sh_writes), jnp.asarray(sh_win))
-    record_paths("cache_scan", paths)
     return correct_padded_stats(stats, counts, sh_pages.shape[1]), counts
 
 
@@ -851,8 +854,7 @@ def init_stream_carry(cfg: StoreConfig, n_shards: int, *, seed: int = 0,
 
 
 def stream_chunk_engine(cfg: StoreConfig, *, unroll: int = 1,
-                        n_windows: int = 1, donate: bool = True,
-                        engine: str = "fused"):
+                        n_windows: int = 1, donate: bool = True):
     """The compiled chunk engine for a structural store config:
     ``(hyper, carry, pages [S, L], writes [S, L], win [S, L]) -> carry``.
 
@@ -865,16 +867,11 @@ def stream_chunk_engine(cfg: StoreConfig, *, unroll: int = 1,
     comment). Callers must treat donated arguments as consumed: thread the
     returned carry, never reuse a chunk buffer after passing it in.
     ``donate=False`` exists for the naive per-chunk baseline benchmarks
-    compare against. ``engine`` selects the fused one-hot request loop
-    (default) or the original ``"scan"`` reference (see
-    :func:`run_stream`); both are bit-exact, masked-pad semantics
-    included. The chunk mode has no Pallas kernel, so ``"pallas"`` runs
-    the fused XLA loop here."""
-    _check_engine(engine)
-    if engine == "pallas":
-        engine = "fused"
+    compare against. Each shard row runs
+    :func:`~repro.kernels.ref.cache_scan_ref` in its masked mode, looked up
+    through this module's name when the engine is traced."""
     static = cfg.static_config()
-    key = (static, unroll, n_windows, donate, engine)
+    key = (static, unroll, n_windows, donate)
     fn = _STREAM_CACHE.get(key)
     if fn is not None:
         return fn
@@ -884,46 +881,15 @@ def stream_chunk_engine(cfg: StoreConfig, *, unroll: int = 1,
         _STREAM_COMPILES[0] += 1  # trace-time: once per XLA compile
 
         def shard(state, acc, p, w, wi):
-            if engine == "fused":
-                # Resumable masked mode, always the XLA engine: pads leave
-                # the carried state (PRNG key included) untouched; the PRNG
-                # stays in-loop because the carried key must advance per
-                # real request.
-                return cache_scan_ref(
-                    state, acc, p, w, wi, hyper, None,
-                    epoch_width=static.epoch_width,
-                    pred_cap=static.pred_cap, prefetch=static.prefetch,
-                    prefetch_width=static.prefetch_width,
-                    n_windows=n_windows, unroll=unroll, masked=True)
-
-            def scan_fn(c, req):
-                state, acc = c
-                page, write, win_i = req
-                valid = win_i < n_windows
-                new_state, out = _step(static, hyper, state,
-                                       (page, write), real=valid)
-                # Masked step: padding leaves the state (including t and
-                # the PRNG key) untouched and contributes nothing to the
-                # scalar totals; the windowed scatters drop pad ids on
-                # their own. ``chosen`` needs no mask — it only feeds
-                # expert_use scaled by the (masked) evict flag.
-                state = jax.tree.map(
-                    lambda n, o: jnp.where(valid, n, o), new_state, state)
-                out = dict(
-                    hit=out["hit"] & valid,
-                    miss=out["miss"] & valid,
-                    prefetch_hit=out["prefetch_hit"] & valid,
-                    tier2_read=jnp.where(valid, out["tier2_read"], 0),
-                    tier2_write=jnp.where(valid, out["tier2_write"], 0),
-                    evict=out["evict"] & valid,
-                    chosen=out["chosen"],
-                )
-                return (state, _fold(acc, out, win_i,
-                                     state.ols.weights)), None
-
-            (state, acc), _ = jax.lax.scan(
-                scan_fn, (state, acc), (p, w, wi), unroll=unroll)
-            return state, acc
+            # Resumable masked mode: pads leave the carried state (PRNG key
+            # included) untouched; the PRNG stays in-loop because the
+            # carried key must advance per real request.
+            return cache_scan_ref(
+                state, acc, p, w, wi, hyper, None,
+                epoch_width=static.epoch_width,
+                pred_cap=static.pred_cap, prefetch=static.prefetch,
+                prefetch_width=static.prefetch_width,
+                n_windows=n_windows, unroll=unroll, masked=True)
 
         state, acc = carry
         return tuple(jax.vmap(shard)(state, acc,
@@ -958,26 +924,7 @@ def stream_stats_from_carry(carry, counts) -> StreamStats:
     the result is directly comparable to :func:`run_distributed`'s
     padding-corrected per-shard stats."""
     state, acc = carry
-    return StreamStats(
-        requests=jnp.asarray(counts, jnp.int32),
-        hits=acc.hits,
-        misses=acc.misses,
-        prefetch_hits=acc.prefetch_hits,
-        tier2_reads=acc.tier2_reads,
-        tier2_writes=acc.tier2_writes,
-        evictions=acc.evictions,
-        expert_use=acc.expert_use,
-        final_weights=state.ols.weights,
-        win_requests=acc.win_requests,
-        win_hits=acc.win_hits,
-        win_misses=acc.win_misses,
-        win_prefetch_hits=acc.win_prefetch_hits,
-        win_tier2_reads=acc.win_tier2_reads,
-        win_tier2_writes=acc.win_tier2_writes,
-        win_evictions=acc.win_evictions,
-        win_expert_use=acc.win_expert_use,
-        win_weights=acc.win_weights,
-    )
+    return _stream_stats(counts, acc, state.ols.weights)
 
 
 def run_stream_chunked(
@@ -991,7 +938,6 @@ def run_stream_chunked(
     unroll: int = 1,
     n_windows: int = 1,
     window_ids: Optional[np.ndarray] = None,
-    engine: str = "fused",
 ) -> StreamStats:
     """Single-shard chunked replay: :func:`run_stream` semantics, consumed
     ``chunk`` requests at a time through the resumable chunk engine.
@@ -1008,8 +954,7 @@ def run_stream_chunked(
     window_ids = np.asarray(window_ids, np.int32)
     if hyper is None:
         hyper = cfg.hyper()
-    eng = stream_chunk_engine(cfg, unroll=unroll, n_windows=n_windows,
-                              engine=engine)
+    eng = stream_chunk_engine(cfg, unroll=unroll, n_windows=n_windows)
     carry = init_stream_carry(cfg, 1, seed=seed, n_windows=n_windows)
     for start in range(0, n, chunk):
         stop = min(start + chunk, n)

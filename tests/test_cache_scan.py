@@ -1,22 +1,21 @@
-"""Fused cache-scan engine exactness harness.
+"""Tier-1 engine exactness harness.
 
-Three rings of defense around the fused tier-1 request loop
-(`repro.kernels.cache_scan.fused_cache_scan` and its megabatch/chunked
-wiring):
+Two rings of defense around the tier-1 request loop
+(`repro.kernels.ref.cache_scan_ref`, wired in by
+`repro.storage.tiered_store.run_stream`, the sweep's megabatch and the
+chunked replay):
 
-1. **Engine parity** — the fused engine is bit-identical to the original
-   per-step ``lax.scan`` reference (``engine="scan"``) over policy ×
+1. **Engine parity** — the engine is bit-identical to the per-step
+   ``lax.scan`` reference (``run_stream_reference``; over a distributed
+   workload, ``engine_reference.reference_tier1_counters``) over policy ×
    mapping × prefetch grids, on windowed, wall-clock-binned, faulted and
-   chunk-streamed workloads, including per-tenant attribution — every
-   counter, not a statistical comparison.
-2. **Kernel goldens** — the Pallas ``cache_scan_kernel`` (interpret mode
-   everywhere; compiled mode under the ``kernels`` marker where a real
-   accelerator backend exists) against the pure-jax oracle
-   ``cache_scan_ref``, the default engine on every platform.
-3. **Invariance fences** — padding/bucketing choices change no windowed
-   counter (pads scatter to the dropped id), sweep results are identical
-   with buffer donation on and off (the undonated path must stay
-   available), and unknown engine names fail fast.
+   chunk-streamed workloads, including per-tenant attribution and rows too
+   long for the hoisted Random-expert table — every counter, not a
+   statistical comparison.
+2. **Invariance fences** — padding/bucketing choices change no windowed
+   counter (pads scatter to the dropped id), and sweep results are
+   identical with buffer donation on and off (the undonated path must stay
+   available).
 
 Property-based fuzzing (hypothesis) deepens ring 1 when the library is
 installed; the fixed-seed tests always run.
@@ -24,20 +23,11 @@ installed; the fixed-seed tests always run.
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
-from repro.kernels.backend import engine_path_counts, reset_engine_path_counts
-from repro.kernels.cache_scan import (
-    KERNEL_WEIGHT_ULP,
-    _chunk_rows,
-    cache_scan_compile_count,
-    cache_scan_kernel,
-    cache_scan_noise,
-    reset_cache_scan_compile_count,
-)
-from repro.kernels.ref import cache_scan_ref
-from repro.sim.engine import tier1_counters
+from engine_reference import reference_tenant_counters, reference_tier1_counters
+from repro.kernels.ref import NOISE_TABLE_MAX
+from repro.sim.engine import batched_reports, tier1_counters
 from repro.sim.spec import (
     FaultSpec,
     RetryPolicy,
@@ -48,9 +38,14 @@ from repro.sim.spec import (
     shard_down,
 )
 from repro.sim.stream import stream_tier1_counters
-from repro.sim.sweep import sweep
+from repro.sim.sweep import (
+    engine_compile_count,
+    expand_grid,
+    reset_engine_compile_count,
+    sweep,
+)
 from repro.core.traffic import TenantSpec
-from repro.storage.tiered_store import init_store, run_stream, _init_accum
+from repro.storage.tiered_store import run_stream, run_stream_reference
 
 # ---------------------------------------------------------------------------
 # helpers
@@ -89,7 +84,7 @@ def _stream(seed, n=1200, n_pages=300, wf=0.3):
 
 
 # ---------------------------------------------------------------------------
-# ring 1: fused engine vs the per-step scan reference
+# ring 1: the engine vs the per-step scan reference
 
 
 @pytest.mark.parametrize("policy", ["ws", "lru", "lfu", "random"])
@@ -99,10 +94,24 @@ def test_run_stream_fused_matches_scan(policy, prefetch):
     win = jnp.asarray(np.minimum(np.arange(1200) // 150, 7), jnp.int32)
     cfg = StoreConfig(n_lines=48, policy=policy, prefetch=prefetch)
     fused = run_stream(cfg, pages, writes, window_ids=win, n_windows=8,
-                       seed=5, engine="fused")
-    scan = run_stream(cfg, pages, writes, window_ids=win, n_windows=8,
-                      seed=5, engine="scan")
+                       seed=5)
+    scan = run_stream_reference(cfg, pages, writes, window_ids=win,
+                                n_windows=8, seed=5)
     _assert_trees_equal(fused, scan, ctx=f"{policy}/pf={prefetch}")
+
+
+@pytest.mark.parametrize("policy", ["ws", "random"])
+def test_run_stream_past_noise_table_matches_scan(policy):
+    """A row whose Random-expert table would exceed NOISE_TABLE_MAX draws
+    in the loop instead: the same draws, so the same counters."""
+    n, n_lines = 65_537, 64
+    assert n * n_lines > NOISE_TABLE_MAX
+    pages, writes = _stream(2, n=n, n_pages=300)
+    cfg = StoreConfig(n_lines=n_lines, policy=policy)
+    fused = run_stream(cfg, pages, writes, n_windows=4, seed=3)
+    scan = run_stream_reference(cfg, pages, writes, n_windows=4, seed=3)
+    assert int(fused.evictions) > 0
+    _assert_trees_equal(fused, scan, ctx=f"past-table/{policy}")
 
 
 @pytest.mark.parametrize("mapping", ["block", "round_robin", "random",
@@ -114,14 +123,14 @@ def test_engine_fused_matches_scan_across_mappings(mapping):
         store=StoreConfig(n_lines=32, policy="ws"),
         n_shards=3, n_windows=6, mapping=mapping,
     )
-    _assert_trees_equal(tier1_counters(spec, engine="fused"),
-                        tier1_counters(spec, engine="scan"), ctx=mapping)
+    _assert_trees_equal(tier1_counters(spec),
+                        reference_tier1_counters(spec), ctx=mapping)
 
 
 def test_engine_fused_matches_scan_faulted_timed():
     """Wall-clock windows + failover remap + retry storm: the fault
-    schedule rides the engine as data, so the fused path must reproduce
-    the scan bit for bit on the degraded timeline too."""
+    schedule rides the engine as data, so the engine must reproduce the
+    reference bit for bit on the degraded timeline too."""
     spec = SimSpec(
         traffic=TrafficSpec(kind="poisson", n_requests=1500, n_pages=400,
                             rate=200.0, seed=7),
@@ -133,20 +142,25 @@ def test_engine_fused_matches_scan_faulted_timed():
             retry=RetryPolicy(timeout=0.05, max_retries=2, backoff_init=0.4),
         ),
     )
-    _assert_trees_equal(tier1_counters(spec, engine="fused"),
-                        tier1_counters(spec, engine="scan"), ctx="faulted")
+    _assert_trees_equal(tier1_counters(spec),
+                        reference_tier1_counters(spec), ctx="faulted")
 
 
-def test_chunked_fused_matches_one_shot_scan():
+@pytest.mark.parametrize("policy", ["ws", "lru", "lfu", "random"])
+@pytest.mark.parametrize("prefetch", [False, True])
+def test_chunked_fused_matches_one_shot_scan(policy, prefetch):
+    """The masked, resumable chunk engine (in-loop Random draws, carried
+    key) against the one-shot reference over the whole stream."""
     spec = SimSpec(
         traffic=TrafficSpec(kind="irm", n_requests=3000, n_pages=400,
                             rate=200.0, seed=3),
+        store=StoreConfig(n_lines=48, policy=policy, prefetch=prefetch),
         n_shards=4, n_windows=8,
     )
-    chunked, _, _ = stream_tier1_counters(spec, chunk=128, engine="fused")
-    one_shot = tier1_counters(spec, engine="scan")
-    _assert_trees_equal(chunked, one_shot, ctx="chunked",
-                        skip=("final_weights", "tenants"))
+    chunked, _, _ = stream_tier1_counters(spec, chunk=128)
+    one_shot = reference_tier1_counters(spec)
+    assert int(one_shot.evictions.sum()) > 0
+    _assert_trees_equal(chunked, one_shot, ctx=f"chunked/{policy}/{prefetch}")
 
 
 def test_chunk_size_invariance_fused():
@@ -155,8 +169,8 @@ def test_chunk_size_invariance_fused():
                             rate=150.0, seed=9),
         n_shards=2, n_windows=5,
     )
-    a, _, _ = stream_tier1_counters(spec, chunk=100, engine="fused")
-    b, _, _ = stream_tier1_counters(spec, chunk=512, engine="fused")
+    a, _, _ = stream_tier1_counters(spec, chunk=100)
+    b, _, _ = stream_tier1_counters(spec, chunk=512)
     _assert_trees_equal(a, b, ctx="chunk-size", skip=("tenants",))
 
 
@@ -170,10 +184,10 @@ def test_tenant_mix_chunked_fused_matches_scan():
         ),
         n_shards=2, n_windows=8,
     )
-    ca, ta, _ = stream_tier1_counters(spec, chunk=256, engine="fused")
-    cb, tb, _ = stream_tier1_counters(spec, chunk=256, engine="scan")
-    _assert_trees_equal(ca, cb, ctx="tenant", skip=("tenants",))
-    _assert_trees_equal(ta, tb, ctx="tenant-attribution")
+    ca, ta, _ = stream_tier1_counters(spec, chunk=256)
+    _assert_trees_equal(ca, reference_tier1_counters(spec), ctx="tenant")
+    _assert_trees_equal(ta, reference_tenant_counters(spec),
+                        ctx="tenant-attribution")
 
 
 def test_sweep_fused_matches_scan():
@@ -184,139 +198,17 @@ def test_sweep_fused_matches_scan():
         n_shards=2, n_windows=4,
     )
     axes = {"store.alpha": (0.3, 0.7), "store.policy": ("ws", "lfu")}
-    fused = sweep(base, axes, engine="fused")
-    scan = sweep(base, axes, engine="scan")
-    assert len(fused.reports) == len(scan.reports) == 4
-    for a, b in zip(fused.reports, scan.reports):
+    fused = sweep(base, axes)
+    specs = [base.replace(**pt) for pt in expand_grid(axes)]
+    scan = batched_reports(
+        [(spec, reference_tier1_counters(spec), None) for spec in specs])
+    assert len(fused.reports) == len(scan) == 4
+    for a, b in zip(fused.reports, scan):
         _assert_reports_equal(a, b, ctx="sweep")
 
 
-def test_sweep_pallas_matches_scan():
-    """``engine="pallas"`` runs the kernel on a TPU and the XLA engine
-    elsewhere — the path counters say which. Counters equal the scan
-    engine's; the weights too, but for the kernel's measured
-    :data:`KERNEL_WEIGHT_ULP` on a TPU."""
-    base = SimSpec(
-        traffic=TrafficSpec(kind="irm", n_requests=800, n_pages=256,
-                            rate=150.0, seed=3),
-        store=StoreConfig(n_lines=24),
-        n_shards=2, n_windows=4,
-    )
-    axes = {"store.alpha": (0.3, 0.7), "store.policy": ("ws", "lru")}
-    reset_engine_path_counts()
-    pallas = sweep(base, axes, engine="pallas")
-    on_tpu = jax.default_backend() == "tpu"
-    assert engine_path_counts()["cache_scan"][
-        "pallas" if on_tpu else "xla"] == 4 * 2
-    scan = sweep(base, axes, engine="scan")
-    for a, b in zip(pallas.reports, scan.reports):
-        _assert_reports_equal(a, b, ctx="sweep-pallas", skip=("weights",))
-        np.testing.assert_array_max_ulp(
-            np.asarray(a.windows.weights, np.float32),
-            np.asarray(b.windows.weights, np.float32),
-            maxulp=KERNEL_WEIGHT_ULP if on_tpu else 0)
-
-
 # ---------------------------------------------------------------------------
-# ring 2: Pallas kernel goldens
-
-
-def _kernel_case(policy, prefetch, seed=1, L=512, N=32, W=8):
-    rng = np.random.default_rng(seed)
-    pages = jnp.asarray(rng.integers(0, 200, L), jnp.int32)
-    writes = jnp.asarray((rng.random(L) < 0.3).astype(np.int32))
-    win = jnp.asarray(np.minimum(np.arange(L) // (L // W), W - 1), jnp.int32)
-    cfg = StoreConfig(n_lines=N, policy=policy, prefetch=prefetch)
-    hyper = cfg.hyper()
-    st0 = init_store(cfg, 9)
-    noise = cache_scan_noise(st0.key, L, N)
-    return cfg, hyper, st0, noise, pages, writes, win, W
-
-
-def _kernel_vs_ref(policy, prefetch, interpret, L=512):
-    cfg, hyper, st0, noise, pages, writes, win, W = _kernel_case(
-        policy, prefetch, L=L)
-    final, acc = cache_scan_ref(
-        st0, _init_accum(W), pages, writes, win, hyper, noise,
-        epoch_width=cfg.epoch_width, pred_cap=cfg.pred_cap,
-        prefetch=cfg.prefetch, prefetch_width=cfg.prefetch_width,
-        n_windows=W)
-    out = cache_scan_kernel(
-        pages[None], writes[None], win[None], noise,
-        hyper.alpha, hyper.beta, hyper.threshold, hyper.policy_idx,
-        n_lines=cfg.n_lines, epoch_width=cfg.epoch_width,
-        pred_cap=cfg.pred_cap, prefetch=cfg.prefetch,
-        prefetch_width=cfg.prefetch_width,
-        prefetch_buf=st0.pf.ptags.shape[-1], n_windows=W,
-        interpret=interpret)
-    for f in acc._fields:
-        x = np.asarray(getattr(acc, f))
-        y = np.asarray(out[f][0]).reshape(x.shape)
-        np.testing.assert_array_equal(
-            y, x, err_msg=f"{policy}/pf={prefetch} field={f}")
-    np.testing.assert_array_equal(np.asarray(out["final_weights"][0]),
-                                  np.asarray(final.ols.weights))
-
-
-@pytest.mark.parametrize("policy,prefetch",
-                         [("ws", False), ("lru", False), ("lfu", False),
-                          ("random", False), ("ws", True), ("random", True)])
-def test_pallas_interpret_matches_ref(policy, prefetch):
-    """Golden: interpret-mode Pallas kernel == pure-jax oracle, bit for
-    bit — counters, windowed telemetry and final expert weights."""
-    _kernel_vs_ref(policy, prefetch, interpret=True)
-
-
-def test_pallas_interpret_multi_chunk_matches_ref():
-    """A row longer than one chunk runs over several grid steps: the state,
-    counters and window weights carried across them match the oracle."""
-    assert _chunk_rows(2500, 32) < 2500
-    _kernel_vs_ref("ws", False, interpret=True, L=2500)
-
-
-def test_pallas_interpret_batched_rows_independent():
-    """Rows of one grid launch must not bleed VMEM scratch state into each
-    other: a [2, L] batch equals two independent single-row launches."""
-    cfg, hyper, st0, noise, pages, writes, win, W = _kernel_case("ws", False)
-    pages2 = jnp.stack([pages, pages[::-1]])
-    writes2 = jnp.stack([writes, writes[::-1]])
-    win2 = jnp.stack([win, win])
-    both = cache_scan_kernel(
-        pages2, writes2, win2, noise,
-        hyper.alpha, hyper.beta, hyper.threshold, hyper.policy_idx,
-        n_lines=cfg.n_lines, epoch_width=cfg.epoch_width,
-        pred_cap=cfg.pred_cap, prefetch=False,
-        prefetch_width=cfg.prefetch_width,
-        prefetch_buf=st0.pf.ptags.shape[-1], n_windows=W, interpret=True)
-    for r in range(2):
-        solo = cache_scan_kernel(
-            pages2[r:r + 1], writes2[r:r + 1], win2[r:r + 1], noise,
-            hyper.alpha, hyper.beta, hyper.threshold, hyper.policy_idx,
-            n_lines=cfg.n_lines, epoch_width=cfg.epoch_width,
-            pred_cap=cfg.pred_cap, prefetch=False,
-            prefetch_width=cfg.prefetch_width,
-            prefetch_buf=st0.pf.ptags.shape[-1], n_windows=W,
-            interpret=True)
-        for f in both:
-            np.testing.assert_array_equal(
-                np.asarray(both[f][r]), np.asarray(solo[f][0]),
-                err_msg=f"row={r} field={f}")
-
-
-@pytest.mark.kernels
-def test_pallas_compiled_matches_ref():
-    """Compiled-mode golden — only meaningful on an accelerator backend
-    (deselect with ``-m 'not kernels'``; auto-skips on CPU, where
-    non-interpret Pallas does not lower)."""
-    if jax.default_backend() == "cpu":
-        pytest.skip("no accelerator backend: compiled Pallas needs TPU/GPU")
-    _kernel_vs_ref("ws", False, interpret=False)
-    _kernel_vs_ref("lru", True, interpret=False)
-    _kernel_vs_ref("ws", False, interpret=False, L=5000)
-
-
-# ---------------------------------------------------------------------------
-# ring 3: invariance fences
+# ring 2: invariance fences
 
 
 def test_padding_does_not_leak_into_windows():
@@ -328,7 +220,7 @@ def test_padding_does_not_leak_into_windows():
     win = jnp.asarray(np.minimum(np.arange(600) // 100, 5), jnp.int32)
     cfg = StoreConfig(n_lines=32, policy="ws")
     base = run_stream(cfg, pages, writes, window_ids=win, n_windows=6,
-                      seed=3, engine="fused")
+                      seed=3)
     for n_pad in (1, 37, 256):
         pad_pages = jnp.concatenate(
             [pages, jnp.full((n_pad,), pages[-1], jnp.int32)])
@@ -337,7 +229,7 @@ def test_padding_does_not_leak_into_windows():
         pad_win = jnp.concatenate(
             [win, jnp.full((n_pad,), 6, jnp.int32)])
         padded = run_stream(cfg, pad_pages, pad_writes, window_ids=pad_win,
-                            n_windows=6, seed=3, engine="fused")
+                            n_windows=6, seed=3)
         for f in base._fields:
             x, y = np.asarray(getattr(base, f)), np.asarray(getattr(padded, f))
             if f == "requests":
@@ -377,17 +269,6 @@ def test_stream_donation_off_matches_on():
     _assert_trees_equal(a, b, ctx="stream-donate", skip=("tenants",))
 
 
-def test_unknown_engine_rejected():
-    pages, writes = _stream(1, n=64)
-    with pytest.raises(ValueError, match="unknown engine"):
-        run_stream(StoreConfig(n_lines=8), pages, writes, engine="bogus")
-    spec = SimSpec(traffic=TrafficSpec(kind="irm", n_requests=64,
-                                       n_pages=32, rate=50.0, seed=1),
-                   n_shards=1)
-    with pytest.raises(ValueError, match="unknown engine"):
-        stream_tier1_counters(spec, engine="bogus")
-
-
 def test_sweep_profile_splits_engine_stage():
     """The engine stage reports its parts (the routed paths, submit and
     wait) as disjoint spans inside the whole call, and the chunked path
@@ -424,8 +305,8 @@ def test_sweep_profile_splits_engine_stage():
 
 def test_compile_count_small_traced_grid():
     """A traced-knob grid (alpha x policy) over one structural config must
-    trace the fused engine at most twice (one-shot megabatch + at most one
-    extra length bucket)."""
+    compile the megabatch engine at most twice (one length bucket + at most
+    one extra), and a repeat of the grid not at all."""
     base = SimSpec(
         traffic=TrafficSpec(kind="irm", n_requests=500, n_pages=160,
                             rate=120.0, seed=12),
@@ -434,10 +315,12 @@ def test_compile_count_small_traced_grid():
     )
     axes = {"store.alpha": (0.3, 0.5, 0.7),
             "store.policy": ("ws", "lru", "lfu")}
-    sweep(base, axes)  # warm the jit/engine caches
-    reset_cache_scan_compile_count()
+    reset_engine_compile_count()
     sweep(base, axes)
-    assert cache_scan_compile_count() <= 2
+    cold = engine_compile_count()
+    assert cold <= 2
+    sweep(base, axes)
+    assert engine_compile_count() == cold
 
 
 # ---------------------------------------------------------------------------
@@ -470,7 +353,7 @@ if HAVE_HYPOTHESIS:
         win = jnp.asarray(rng.integers(0, 4, n), jnp.int32)
         cfg = StoreConfig(n_lines=n_lines, policy=policy, prefetch=prefetch)
         fused = run_stream(cfg, pages, writes, window_ids=win, n_windows=4,
-                           seed=seed % 7, engine="fused")
-        scan = run_stream(cfg, pages, writes, window_ids=win, n_windows=4,
-                          seed=seed % 7, engine="scan")
+                           seed=seed % 7)
+        scan = run_stream_reference(cfg, pages, writes, window_ids=win,
+                                    n_windows=4, seed=seed % 7)
         _assert_trees_equal(fused, scan, ctx=f"fuzz-{seed}")

@@ -1,14 +1,17 @@
-"""Compile rehearsal: the main path's Pallas kernels compile for a TPU v5e.
+"""Compile rehearsal: the main path compiles for a TPU v5e.
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
-topology. Each test compiles one program at the widths ``chip_smoke.py``
-runs and checks that the kernel is in it (``tpu_custom_call``). What the
+topology. Each test compiles one program at the widths the benchmark cells
+run: the reuse-distance kernel (checked to be in the program,
+``tpu_custom_call``), and the tier-1 engines the replay and sweep cells
+run, which are XLA alone (checked to hold no custom call). What the
 compiler refuses here — unaligned blocks, scalar reads it cannot prove,
-more VMEM than the kernel may use — would otherwise surface only on the
+more VMEM than a kernel may use — would otherwise surface only on the
 chip. The topology is described in a fixture (never at import), and only
 this file does so.
 """
 import os
+import warnings
 
 import pytest
 
@@ -16,15 +19,22 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.cache_scan import cache_scan_kernel, kernel_fits
 from repro.kernels.reuse_distance import reuse_distance_kernel
-from repro.sim.sweep import _batched_engine
-from repro.storage.tiered_store import StoreConfig, StoreHyper
+from repro.sim.sweep import DEFAULT_UNROLL, _batched_engine
+from repro.storage.tiered_store import (
+    StoreConfig,
+    StoreHyper,
+    init_stream_carry,
+    stream_chunk_engine,
+)
 
-# chip_smoke.py phase B (bench_engine's grid x4): busiest-shard bucket,
-# lines per shard, windows; phase A/C: 16 shards, per-shard bucket.
-PHASE_B_LEN, PHASE_B_LINES, N_WINDOWS = 8192, 256, 32
+# chip_smoke.py phase C: 16 shards, per-shard bucket.
 PHASE_C_SHARDS, PHASE_C_LEN = 16, 1 << 20
+# The replay cells: 16 shards, the chunk bucket (rung) their hottest shard
+# takes, 32 windows. The knob sweep: 32 points of 4 shards, the bucket of
+# its busiest shard.
+REPLAY_SHARDS, REPLAY_RUNG, N_WINDOWS = 16, 1 << 16, 32
+SWEEP_POINTS, SWEEP_SHARDS, SWEEP_LINES, SWEEP_LEN = 32, 4, 64, 4096
 
 
 @pytest.fixture(scope="module")
@@ -50,45 +60,14 @@ def _shape(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compiled_text(fn, *args) -> str:
-    return jax.jit(fn).lower(*args).compile().as_text()
-
-
-def _cache_scan_text(one_chip, length, n_lines, *, prefetch, policy_idx):
-    def fn(pages, writes, win, noise, knobs):
-        return cache_scan_kernel(
-            pages, writes, win, noise, knobs[0], knobs[1], knobs[2],
-            policy_idx, n_lines=n_lines, prefetch=prefetch,
-            n_windows=N_WINDOWS)
-
-    i32 = jnp.int32
-    return _compiled_text(
-        fn, _shape(one_chip, (1, length), i32),
-        _shape(one_chip, (1, length), i32),
-        _shape(one_chip, (1, length), i32),
-        _shape(one_chip, (length, n_lines), jnp.float32),
-        _shape(one_chip, (3,), jnp.float32))
-
-
-@pytest.mark.parametrize("prefetch,policy_idx", [(False, -1), (True, 0)],
-                         ids=["ws", "lru_prefetch"])
-def test_cache_scan_kernel_compiles_at_phase_b_widths(one_chip, prefetch,
-                                                      policy_idx):
-    assert kernel_fits(PHASE_B_LEN, PHASE_B_LINES)
-    text = _cache_scan_text(one_chip, PHASE_B_LEN, PHASE_B_LINES,
-                            prefetch=prefetch, policy_idx=policy_idx)
-    assert "tpu_custom_call" in text
-
-
-def test_cache_scan_kernel_compiles_at_vmem_budget(one_chip):
-    """The widest cache the engine-path rule sends to the kernel compiles:
-    the rule's VMEM budget stays inside what Mosaic grants."""
-    length = 256
-    widest = max(n for n in range(128, 1 << 16, 128)
-                 if kernel_fits(length, n))
-    text = _cache_scan_text(one_chip, length, widest, prefetch=True,
-                            policy_idx=-1)
-    assert "tpu_custom_call" in text
+def _compiled_text(fn, *args, donate=()) -> str:
+    with warnings.catch_warnings():
+        # As in the engines: donated stream operands with no same-shape
+        # output can only be freed early.
+        warnings.filterwarnings("ignore",
+                                message="Some donated buffers were not usable")
+        return (jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+                .as_text())
 
 
 def test_reuse_distance_kernel_compiles_at_phase_c_length(one_chip):
@@ -99,17 +78,39 @@ def test_reuse_distance_kernel_compiles_at_phase_c_length(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_sweep_engine_lowers_to_the_kernel_for_tpu(one_chip):
-    """The megabatch sweep engine (vmapped rows, platform dispatch) takes
-    the Pallas branch when it is compiled for a TPU."""
-    store = StoreConfig(n_lines=PHASE_B_LINES).static_config()
-    eng = _batched_engine(store, 1, (one_chip.device_set.pop(),), N_WINDOWS,
-                          engine="pallas", donate=False)
-    n, s = 4, 4
-    hyper = StoreHyper(*(_shape(one_chip, (n,), dt) for dt in
-                         (jnp.float32, jnp.float32, jnp.float32, jnp.int32)))
-    text = eng.lower(
-        hyper, _shape(one_chip, (n, s, PHASE_B_LEN), jnp.int32),
-        _shape(one_chip, (n, s, PHASE_B_LEN), jnp.bool_),
-        _shape(one_chip, (n, s, PHASE_B_LEN), jnp.int32)).compile().as_text()
-    assert "tpu_custom_call" in text
+def _hyper(sharding, shape=()):
+    return StoreHyper(*(_shape(sharding, shape, dt) for dt in
+                        (jnp.float32, jnp.float32, jnp.float32, jnp.int32)))
+
+
+@pytest.mark.parametrize("n_lines,policy", [(2048, "lru"), (4096, "ws")],
+                         ids=["wl2_replay", "wl1_ws_replay"])
+def test_chunk_engine_compiles_at_replay_widths(one_chip, n_lines, policy):
+    """The replay cells' chunk engine (donated carry and chunk buffers)
+    compiles at their rung, on XLA alone."""
+    cfg = StoreConfig(n_lines=n_lines, policy=policy)
+    eng = stream_chunk_engine(cfg, n_windows=N_WINDOWS)
+    carry = jax.tree.map(
+        lambda x: _shape(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lambda: init_stream_carry(
+            cfg, REPLAY_SHARDS, n_windows=N_WINDOWS)))
+    rows = (REPLAY_SHARDS, REPLAY_RUNG)
+    text = _compiled_text(
+        eng, _hyper(one_chip), carry, _shape(one_chip, rows, jnp.int32),
+        _shape(one_chip, rows, jnp.bool_), _shape(one_chip, rows, jnp.int32),
+        donate=(1, 2, 3, 4))
+    assert "tpu_custom_call" not in text
+
+
+def test_sweep_engine_compiles_at_knob_sweep_widths(one_chip):
+    """The knob sweep's megabatch engine compiles at its grid, on XLA
+    alone."""
+    store = StoreConfig(n_lines=SWEEP_LINES).static_config()
+    eng = _batched_engine(store, DEFAULT_UNROLL,
+                          (one_chip.device_set.pop(),), N_WINDOWS)
+    rows = (SWEEP_POINTS, SWEEP_SHARDS, SWEEP_LEN)
+    text = _compiled_text(
+        eng, _hyper(one_chip, (SWEEP_POINTS,)),
+        _shape(one_chip, rows, jnp.int32), _shape(one_chip, rows, jnp.bool_),
+        _shape(one_chip, rows, jnp.int32), donate=(1, 2, 3))
+    assert "tpu_custom_call" not in text
